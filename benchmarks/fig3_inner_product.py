@@ -50,4 +50,7 @@ def run(rounds: int = 200, verbose: bool = True) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

@@ -373,4 +373,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -632,15 +632,9 @@ def bench_mesh(argv):
 
 
 if __name__ == "__main__":
-    if "--drivers" in sys.argv[1:]:
-        bench_drivers(sys.argv[1:])
-    elif "--data-plane" in sys.argv[1:]:
-        bench_data_plane(sys.argv[1:])
-    elif "--secure" in sys.argv[1:]:
-        bench_secure(sys.argv[1:])
-    elif "--mesh" in sys.argv[1:]:
-        # XLA_FLAGS must be final before anything imports jax: force 8
-        # host devices when the user didn't pin a count, and turn on the
+    if "--mesh" in sys.argv[1:]:
+        # XLA_FLAGS must be final before jax initializes its backends: force
+        # 8 host devices when the user didn't pin a count, and turn on the
         # latency-hiding scheduler so the psum overlaps with per-shard
         # compute where XLA can manage it (async collectives are default-on
         # in this XLA; its old opt-in flag no longer parses)
@@ -653,6 +647,16 @@ if __name__ == "__main__":
         if _f not in _flags:
             _flags += " " + _f
         os.environ["XLA_FLAGS"] = _flags.strip()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    if "--drivers" in sys.argv[1:]:
+        bench_drivers(sys.argv[1:])
+    elif "--data-plane" in sys.argv[1:]:
+        bench_data_plane(sys.argv[1:])
+    elif "--secure" in sys.argv[1:]:
+        bench_secure(sys.argv[1:])
+    elif "--mesh" in sys.argv[1:]:
         bench_mesh(sys.argv[1:])
     else:
         main(sys.argv[1:] or ["results/hillclimb.jsonl"])

@@ -157,4 +157,7 @@ def _run_extra(module: str):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

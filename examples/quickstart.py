@@ -13,7 +13,7 @@ plane trains the SAME trajectory, only the engine/data placement differs.
 the decision is printed and logged.  The legacy ``--scanned`` /
 ``--device-data`` / ``--stream-data`` flags remain as aliases.
 ``--fused-server`` independently routes FedMom through the fused Pallas
-server update (a win on TPU; interpret mode on CPU).  ``--hetero``
+server update (compiled on a TPU; interpret mode on the CPU).  ``--hetero``
 additionally gives each client a random H_k <= H of local work per round
 (the straggler / partial-work scenario).
 
@@ -404,4 +404,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -25,7 +25,6 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import client as client_lib
@@ -122,8 +121,13 @@ def _shard_map_round(loss_fn, opt, w_c, batches, weights, step_mask, lr,
                 loss_fn, p, b, lr_rep, opt, step_mask=m)
 
         C_s = ws.shape[0]
+        # each shard's client replicas are device-varying: left invariant,
+        # autodiff would transpose the implicit invariant->varying cast
+        # into a psum and hand every client the cross-device gradient sum
         local0 = jax.tree.map(
-            lambda p: jnp.broadcast_to(p[None], (C_s,) + p.shape), w_rep)
+            lambda p: jax.lax.pcast(
+                jnp.broadcast_to(p[None], (C_s,) + p.shape), axes,
+                to="varying"), w_rep)
         final, losses = (jax.vmap(one)(local0, bs) if ms is None
                          else jax.vmap(one)(local0, bs, ms))
         part = jax.tree.map(
@@ -135,12 +139,12 @@ def _shard_map_round(loss_fn, opt, w_c, batches, weights, step_mask, lr,
 
     rep = jax.tree.map(lambda _: P(), w_c)
     if step_mask is None:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda w, l, ws, bs: body(w, l, ws, bs, None), mesh=mesh,
             in_specs=(rep, P(), lead, lead), out_specs=(rep, lead))
         delta, losses = fn(w_c, lr, weights, batches)
     else:
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(rep, P(), lead, lead, lead), out_specs=(rep, lead))
         delta, losses = fn(w_c, lr, weights, batches, step_mask)

@@ -82,9 +82,10 @@ def fedmom(eta: float = 1.0, beta: float = 0.9, *,
     beta=0.9 everywhere in the paper's experiments.  ``use_fused_kernel``
     routes the elementwise update through the Pallas kernel
     (kernels/fedmom_update) — one HBM pass instead of three ops.
-    ``interpret`` pins the kernel's interpret mode for jitted launches whose
-    target device differs from ``jax.default_backend()`` (inside jit the
-    operands are tracers, so the kernel cannot see the real target itself).
+    ``interpret`` pins the kernel's mode for a program jitted by hand
+    (inside jit the operands are tracers, so the kernel cannot see its
+    target); ``FederatedTrainer.run`` names the platform itself, and
+    interpret mode is refused in a TPU process (``kernels/_device.py``).
     """
     def init_extra(w):
         return {"v": jax.tree.map(jnp.copy, w)}   # v_0 = w_0

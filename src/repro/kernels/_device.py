@@ -1,39 +1,106 @@
-"""Interpret-mode resolution shared by the kernel zoo's ops wrappers.
+"""Interpret-mode resolution shared by the main-path kernels' ops wrappers.
 
-Every Pallas kernel here compiles on TPU and runs the same body in interpret
-mode elsewhere.  Picking the mode from ``jax.default_backend()`` alone is a
-trace-time guess: a launch committed to a non-default device (e.g. CPU arrays
-in a TPU-default process, or an explicit ``jax.device_put``) would get the
-wrong mode and either miscompile or crash in lowering.  ``resolve_interpret``
-therefore inspects the ACTUAL operands first — a concrete array knows the
-device it is committed to — and only falls back to the default backend for
-tracers (inside jit the caller should thread an explicit ``interpret=`` from
-whoever knows the launch target, e.g. the trainer).
+A Pallas TPU kernel is compiled on a TPU and interpreted on the CPU (the
+tests).  Nothing else: on a TPU it never interprets, and a kernel whose
+target cannot be told raises instead of guessing.  The target comes from,
+in order:
+
+1. the operands — a concrete array knows the device it is committed to;
+2. ``kernel_platform(...)`` — inside jit the operands are tracers, so the
+   caller that knows where the program runs names it.  ``FederatedTrainer``
+   enters it once per ``run()`` with the platform of the devices it places
+   on, which threads the choice into every round it traces;
+3. an explicit ``interpret=`` — for a kernel compiled by hand, e.g. for a
+   described chip in a CPU process (``tests/test_tpu_compile.py``).
+
+An explicit ``interpret=`` that contradicts (1) or (2) raises, and so does
+interpret mode in a process whose default backend is a TPU.
+
+``whole_on_each_device`` covers the mesh planes: the TPU compiler cannot
+partition a Mosaic kernel, so under an active mesh a kernel runs whole on
+every device, inside a ``shard_map`` with replicated operands.
 """
 from __future__ import annotations
 
+import contextlib
+import threading
 from typing import Any, Optional
 
 import jax
+from jax.sharding import PartitionSpec as P
+
+from repro.sharding import current_mesh
+
+_scope = threading.local()
+
+
+@contextlib.contextmanager
+def kernel_platform(platform: str):
+    """Jitted kernels traced in this thread (and scope) target ``platform``
+    ("tpu" or "cpu")."""
+    interpret_for(platform)            # validate early
+    prev = getattr(_scope, "platform", None)
+    _scope.platform = platform
+    try:
+        yield
+    finally:
+        _scope.platform = prev
+
+
+def interpret_for(platform: str) -> bool:
+    """Compiled on a TPU, interpreted on the CPU, refused elsewhere."""
+    if platform not in ("tpu", "cpu"):
+        raise ValueError(
+            f"the Pallas kernels here target 'tpu' (compiled) or 'cpu' "
+            f"(interpret mode); got platform {platform!r}")
+    return platform == "cpu"
+
+
+def _operand_platforms(operands: Any) -> set:
+    out = set()
+    for x in jax.tree.leaves(operands):
+        if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
+            out.update(d.platform for d in x.devices())
+    return out
 
 
 def resolve_interpret(operands: Any, interpret: Optional[bool] = None) -> bool:
-    """True when the kernel must run in interpret mode (non-TPU target).
+    """True when the kernel must run in interpret mode (CPU target)."""
+    platforms = _operand_platforms(operands)
+    if len(platforms) > 1:
+        raise ValueError(
+            f"kernel operands span several platforms {sorted(platforms)}")
+    if platforms:
+        (platform,) = platforms
+        if platform == "cpu" and jax.default_backend() == "tpu":
+            raise ValueError(
+                "kernel operands are committed to the CPU in a TPU process; "
+                "put them on the TPU (jax.device_put) — the kernel does not "
+                "fall back to interpret mode beside a chip")
+        mode = interpret_for(platform)
+    elif getattr(_scope, "platform", None) is not None:
+        mode = interpret_for(_scope.platform)
+    elif interpret is not None:
+        mode = bool(interpret)
+    else:
+        raise ValueError(
+            "inside jit the kernel cannot see its target device: trace it "
+            "under kernels._device.kernel_platform(...) (FederatedTrainer."
+            "run does) or pass interpret= explicitly")
+    if interpret is not None and bool(interpret) != mode:
+        raise ValueError(
+            f"interpret={interpret} contradicts the kernel's target "
+            f"({'cpu' if mode else 'tpu'})")
+    if mode and jax.default_backend() == "tpu":
+        raise ValueError("interpret mode is refused in a TPU process")
+    return mode
 
-    ``interpret`` is authoritative when given (the threaded override).
-    Otherwise the first concrete operand's committed device decides; only
-    when every operand is a tracer (inside jit, devices unknowable) does
-    ``jax.default_backend()`` break the tie.
-    """
-    if interpret is not None:
-        return bool(interpret)
-    for x in jax.tree.leaves(operands):
-        if isinstance(x, jax.core.Tracer):
-            continue
-        if isinstance(x, jax.Array):
-            try:
-                dev = next(iter(x.devices()))
-            except Exception:
-                continue
-            return dev.platform != "tpu"
-    return jax.default_backend() != "tpu"
+
+def whole_on_each_device(fn):
+    """``fn`` itself outside a mesh; under the active mesh context, ``fn``
+    run whole on every device of it (replicated operands and results)."""
+    mesh = current_mesh()
+    if mesh is None:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)

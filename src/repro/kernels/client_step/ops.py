@@ -21,18 +21,19 @@ The fusion contract (what the engine hands over, what it gets back):
 ``linreg_tier_step`` adapts this to the ``client_step_fn`` hook of
 ``core.multiround.scan_rounds_bucketed`` for the linear-regression family
 (fields ``{'x', 'y'}``, params ``{'w', 'b'}``).  Interpret mode resolves
-from the actual operand devices (``kernels._device.resolve_interpret``)
-with an explicit ``interpret=`` override for jitted launches.
+through ``kernels._device.resolve_interpret``: compiled on a TPU,
+interpreted on the CPU only.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.data.federated import minibatch_indices
-from repro.kernels._device import resolve_interpret
+from repro.kernels._device import resolve_interpret, whole_on_each_device
 from repro.kernels.client_step import kernel as _k
 from repro.kernels.client_step import ref as _ref
 
@@ -62,18 +63,19 @@ def client_step(xs, ys, slots, idx, w, b, lr, local_steps: int,
     Np, Dp = _round_up(N, _k.SUBLANE), _round_up(D, _k.LANE)
     xs_p = jnp.pad(xs.astype(jnp.float32),
                    ((0, 0), (0, Np - N), (0, Dp - D)))
-    ys_p = jnp.pad(ys.astype(jnp.float32), ((0, 0), (0, Np - N)))
+    ys_p = jnp.pad(ys.astype(jnp.float32), ((0, 0), (0, Np - N)))[..., None]
     w_p = jnp.pad(jnp.reshape(w, (1, D)).astype(jnp.float32),
                   ((0, 0), (0, Dp - D)))
     b_p = jnp.reshape(b, (1, 1)).astype(jnp.float32)
     lr_p = jnp.reshape(jnp.asarray(lr), (1, 1)).astype(jnp.float32)
     mask = (jnp.ones((C, H), jnp.float32) if step_mask is None
             else jnp.asarray(step_mask).astype(jnp.float32))
-    wo, bo, lo = _k.client_step_flat(
-        xs_p, ys_p, jnp.asarray(slots, jnp.int32),
-        jnp.asarray(idx, jnp.int32), w_p, b_p, lr_p, mask,
-        local_steps=H, batch_size=int(batch_size), interpret=interpret)
-    return wo[:, :D], bo[:, 0], lo[:, 0]
+    launch = whole_on_each_device(functools.partial(
+        _k.client_step_flat, local_steps=H, batch_size=int(batch_size),
+        interpret=interpret))
+    wo, bo, lo = launch(xs_p, ys_p, jnp.asarray(slots, jnp.int32),
+                        jnp.asarray(idx, jnp.int32), w_p, b_p, lr_p, mask)
+    return wo[:, 0, :D], bo[:, 0, 0], lo[:, 0, 0]
 
 
 def linreg_tier_step(use_kernel: bool = True,

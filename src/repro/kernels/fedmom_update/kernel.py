@@ -59,7 +59,7 @@ _BODIES = {"fedmom": _fedmom_body, "fedavgm": _fedavgm_body}
 @functools.partial(jax.jit,
                    static_argnames=("kind", "eta", "beta", "interpret"))
 def fused_flat(w: jax.Array, s: jax.Array, delta: jax.Array, kind: str,
-               eta: float, beta: float, interpret: bool = True):
+               eta: float, beta: float, *, interpret: bool):
     """w / momentum-state s / delta: [rows, 128] fp32 (rows a multiple of
     BLOCK_ROWS).  Returns (w', s') for the selected update ``kind``."""
     rows = w.shape[0]
@@ -97,7 +97,7 @@ def _unpack(packed, leaves):
 
 
 def fused_update_tree(w_tree, v_tree, d_tree, *, eta: float, beta: float,
-                      interpret: bool = True, kind: str = "fedmom",
+                      interpret: bool, kind: str = "fedmom",
                       fuse_tree: bool = True):
     """Applies the fused update over parameter pytrees.
 

@@ -318,7 +318,7 @@ def dry_run(arch: str, shape_name: str, *, multi_pod: bool = False,
 
         # ---- roofline ---------------------------------------------------
         terms = ha.roofline_terms(flops, bytes_accessed,
-                                  la["collective_bytes"])
+                                  la["collective_bytes"], hw.V5E)
         rec["roofline"] = terms
 
         tokens = shape.global_batch * (shape.seq if shape.kind != "decode"

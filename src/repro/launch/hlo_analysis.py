@@ -61,10 +61,13 @@ def parse_collectives(hlo_text: str) -> Dict[str, dict]:
 
 
 def roofline_terms(flops_per_dev: float, hbm_bytes_per_dev: float,
-                   coll_bytes_per_dev: float) -> dict:
-    compute_s = flops_per_dev / hw.PEAK_FLOPS_BF16
-    memory_s = hbm_bytes_per_dev / hw.HBM_BW
-    collective_s = coll_bytes_per_dev / hw.ICI_BW
+                   coll_bytes_per_dev: float, device_kind: str) -> dict:
+    """Lower-bound seconds per term against ``device_kind``'s declared
+    peaks (``hw.peaks`` raises for an undeclared device)."""
+    pk = hw.peaks(device_kind)
+    compute_s = flops_per_dev / pk.flops_bf16
+    memory_s = hbm_bytes_per_dev / pk.hbm_bw
+    collective_s = coll_bytes_per_dev / pk.ici_bw
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s}
     dom = max(terms, key=terms.get)

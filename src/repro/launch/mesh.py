@@ -1,8 +1,14 @@
 """Production meshes + the declarative ``MeshSpec`` plans carry.
 
 Functions, not module-level constants, so importing this module never
-touches jax device state.  TPU v5e numbers (roofline constants) live in
-repro.launch.hw.
+touches jax device state.  Peak device numbers live in repro.launch.hw.
+
+Every mesh here is built by ``make_mesh`` with ``AxisType.Auto`` axes: the
+round engine places data with ``with_sharding_constraint`` (GSPMD) and
+splits the cohort with ``jax.shard_map``, both of which expect compiler-
+chosen (Auto) propagation.  ``jax.make_mesh`` defaults to ``Explicit``
+axes, under which those constraints and the in-scan gathers raise
+``ShardingTypeError``.
 """
 from __future__ import annotations
 
@@ -10,6 +16,14 @@ from dataclasses import dataclass
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` over the local devices with Auto-typed axes (see
+    the module docstring for why not Explicit)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 @dataclass(frozen=True)
@@ -57,18 +71,18 @@ class MeshSpec:
                 f"{jax.device_count()} are visible (force host devices "
                 f"with XLA_FLAGS=--xla_force_host_platform_device_count=N "
                 f"before jax initializes)")
-        return jax.make_mesh((n,), (self.axis,))
+        return make_mesh((n,), (self.axis,))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Tiny mesh over the locally available devices (CPU tests/examples)."""
     n = jax.device_count()
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

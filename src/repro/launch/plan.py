@@ -299,20 +299,25 @@ class PlanDecision:
 
 
 def device_memory_budget() -> Optional[int]:
-    """Device memory limit in bytes, when the backend reports one (TPU/GPU
-    ``memory_stats()['bytes_limit']``); ``None`` on backends that don't
-    (CPU) — the auto rule then treats memory as unbounded unless the plan
-    carries an explicit ``memory_budget_bytes``."""
+    """Device memory limit in bytes (``memory_stats()['bytes_limit']``).
+
+    ``None`` on the CPU, which reports no limit — the auto rule then treats
+    memory as unbounded unless the plan carries an explicit
+    ``memory_budget_bytes``.  On a TPU the limit is read, never assumed: a
+    TPU that reports none raises rather than letting ``plan="auto"`` pick
+    a plane without a budget."""
     import jax
 
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
+    dev = jax.local_devices()[0]
+    if dev.platform == "cpu":
         return None
-    if not stats:
-        return None
+    stats = dev.memory_stats() or {}
     limit = stats.get("bytes_limit")
-    return int(limit) if limit else None
+    if not limit:
+        raise PlanError(
+            f"{dev.device_kind} reports no memory_stats()['bytes_limit']; "
+            f"pass ExecutionPlan(memory_budget_bytes=...) explicitly")
+    return int(limit)
 
 
 # chunk_rounds="auto": amortize the measured per-dispatch overhead (host

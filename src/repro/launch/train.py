@@ -87,6 +87,7 @@ from repro.core.server_opt import ServerOpt, ServerState
 from repro.data.device import DeviceFederatedDataset
 from repro.data.federated import FederatedDataset, minibatch_indices
 from repro.data.stream import ShardCache, StreamingFederatedDataset
+from repro.kernels._device import kernel_platform
 from repro.launch.mesh import MeshSpec
 from repro.launch.plan import (CacheSpec, CkptSpec, ExecutionPlan, PlanError,
                                TrainSession, _IdKey, as_plan, resolve)
@@ -487,11 +488,16 @@ class FederatedTrainer:
             # constraints everywhere, the explicit shard_map+psum plane in
             # round_step when the mesh is pure data-parallel).  mesh=None
             # activates nothing — the pre-mesh code path, bit for bit.
-            mesh_ctx = (axis_rules(self.session.mesh_for(plan.mesh),
-                                   FED_MESH_RULES)
-                        if plan.mesh is not None
-                        else contextlib.nullcontext())
-            with mesh_ctx:
+            mesh = (self.session.mesh_for(plan.mesh)
+                    if plan.mesh is not None else None)
+            mesh_ctx = (axis_rules(mesh, FED_MESH_RULES)
+                        if mesh is not None else contextlib.nullcontext())
+            # the Pallas kernels' mode follows the platform this run places
+            # on (compiled on a TPU, interpreted on the CPU), decided here
+            # once for every program the planes trace
+            device = (mesh.devices.flat[0] if mesh is not None
+                      else jax.devices()[0])
+            with mesh_ctx, kernel_platform(device.platform):
                 if decision.plane == "per_round":
                     return self._run_per_round(n_rounds, cadence, eval_fn,
                                                verbose, resume)

@@ -8,6 +8,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.core import RoundConfig, round_step, fedmom
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.sharding import FED_MESH_RULES, axis_rules, tree_shardings
 
@@ -35,8 +36,8 @@ def test_round_under_mesh_context_matches_plain():
     s_plain, m_plain = round_step(loss_fn, opt, opt.init(params), batches,
                                   weights, rcfg, param_axes=axes)
 
-    n = jax.device_count()
-    mesh = jax.make_mesh((1, n, 1), ("pod", "data", "model"))
+    n = min(jax.device_count(), C)          # the cohort must tile 'data'
+    mesh = make_mesh((1, n, 1), ("pod", "data", "model"))
     rules = dict(FED_MESH_RULES, batch=None)
     with mesh, axis_rules(mesh, rules):
         s_mesh, m_mesh = round_step(loss_fn, opt, opt.init(params), batches,

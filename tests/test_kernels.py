@@ -24,7 +24,8 @@ def test_fedmom_kernel_sweep(shape, dtype, eta, beta):
     w = {"p": jax.random.normal(ks[0], shape).astype(dtype)}
     v = {"p": jax.random.normal(ks[1], shape).astype(dtype)}
     d = {"p": (0.01 * jax.random.normal(ks[2], shape)).astype(dtype)}
-    w1, v1 = fm_k.fused_update_tree(w, v, d, eta=eta, beta=beta)
+    w1, v1 = fm_k.fused_update_tree(w, v, d, eta=eta, beta=beta,
+                                    interpret=True)
     w2, v2 = fm_ref.fedmom_update(w, v, d, eta, beta)
     atol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(w1["p"], np.float32),
@@ -59,7 +60,7 @@ def test_fedmom_kernel_mixed_tree_edges(fuse_tree):
     per-leaf launches vs the unfused v'=w-eta*d; w'=v'+beta*(v'-v) oracle."""
     w, v, d = _mixed_tree(1)
     w1, v1 = fm_k.fused_update_tree(w, v, d, eta=1.5, beta=0.9,
-                                    fuse_tree=fuse_tree)
+                                    fuse_tree=fuse_tree, interpret=True)
     w2, v2 = fm_ref.fedmom_update(w, v, d, 1.5, 0.9)
     # output dtypes must follow the input leaves, not the f32 stream
     assert all(w1[k].dtype == w[k].dtype for k in w)
@@ -75,9 +76,9 @@ def test_fedmom_packed_equals_per_leaf_exactly():
     single-launch stream must agree with per-leaf launches bitwise."""
     w, v, d = _mixed_tree(2)
     w1, v1 = fm_k.fused_update_tree(w, v, d, eta=2.0, beta=0.7,
-                                    fuse_tree=True)
+                                    fuse_tree=True, interpret=True)
     w2, v2 = fm_k.fused_update_tree(w, v, d, eta=2.0, beta=0.7,
-                                    fuse_tree=False)
+                                    fuse_tree=False, interpret=True)
     for k in w:
         np.testing.assert_array_equal(np.asarray(w1[k]), np.asarray(w2[k]))
         np.testing.assert_array_equal(np.asarray(v1[k]), np.asarray(v2[k]))
@@ -91,7 +92,7 @@ def test_fedavgm_kernel_sweep(shape, eta, beta):
     m = {"p": jax.random.normal(ks[1], shape)}
     d = {"p": 0.01 * jax.random.normal(ks[2], shape)}
     w1, m1 = fm_k.fused_update_tree(w, m, d, eta=eta, beta=beta,
-                                    kind="fedavgm")
+                                    kind="fedavgm", interpret=True)
     w2, m2 = fm_ref.fedavgm_update(w, m, d, eta, beta)
     np.testing.assert_allclose(np.asarray(w1["p"]), np.asarray(w2["p"]),
                                atol=1e-5)

@@ -17,9 +17,9 @@ contract certified here:
   when the plan carries a mesh, and the flip is audited in ``plan_log``
   with ``mesh_shape`` / ``axis_names`` / ``per_device_nbytes``.
 
-The sharded rows need >= 4 host devices: run under
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (the mesh-sharded
-CI lane does); on a plain 1-device host they skip.
+The sharded rows need >= 4 host devices; ``tests/conftest.py`` forces
+four unless ``XLA_FLAGS`` already sets a count (the mesh-sharded CI lane
+sets eight).  With fewer they skip.
 """
 import jax
 import numpy as np
@@ -31,6 +31,7 @@ from _trajectory import (assert_bitwise_trajectory, assert_same_trajectory,
 from repro.core import fedmom
 from repro.core.secure_agg import SecureAggSpec
 from repro.data.stream import MeshShardedCache, StreamingFederatedDataset
+from repro.kernels.client_step.ops import linreg_tier_step
 from repro.launch.mesh import MeshSpec
 from repro.launch.plan import ExecutionPlan, PlanError
 
@@ -88,6 +89,26 @@ def test_sharded_resume_matches_straight_run(tmp_path):
                              chunk_rounds=4, mesh=MESH4, resume_at=5,
                              tmp_path=tmp_path)
     assert_same_trajectory(resumed, straight, atol=1e-6)
+
+
+@needs_mesh
+@pytest.mark.parametrize("driver", ["device", "streaming-bucketed"])
+def test_sharded_plane_with_fused_kernels(driver):
+    """The TPU compiler cannot partition a Pallas kernel, so under a mesh
+    each fused kernel runs whole on every device: the FedMom server update
+    (device plane) and the client step (bucketed streaming) still train the
+    one-device trajectory."""
+    clients = make_clients(n=N_CLIENTS)
+    def opt():
+        return fedmom(eta=1.0, beta=0.9, use_fused_kernel=driver == "device")
+
+    kw = ({} if driver == "device"
+          else {"client_step_fn": linreg_tier_step(use_kernel=True)})
+    want = run_trajectory(driver, opt(), _rcfg(), clients, 8,
+                          chunk_rounds=4, **kw)
+    got = run_trajectory(driver, opt(), _rcfg(), clients, 8,
+                         chunk_rounds=4, mesh=MESH4, **kw)
+    assert_same_trajectory(got, want, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------

@@ -182,6 +182,7 @@ def test_scan_placement_accepts_param_axes():
     """Regression for the scan-placement sharding fix: param_axes must
     thread through the scan body (broadcast model + fp32 accumulator
     constraints) and leave the math identical to the unsharded run."""
+    from repro.launch.mesh import make_mesh
     from repro.sharding import FED_MESH_RULES, axis_rules
 
     params, batches, weights = _setup(seed=8)
@@ -192,7 +193,7 @@ def test_scan_placement_accepts_param_axes():
     ref, ref_m = round_step(linreg_loss, opt, opt.init(params), batches,
                             weights, rcfg)
     n = jax.device_count()
-    mesh = jax.make_mesh((n, 1), ("data", "model"))
+    mesh = make_mesh((n, 1), ("data", "model"))
     with mesh, axis_rules(mesh, FED_MESH_RULES):
         got, got_m = round_step(linreg_loss, opt, opt.init(params), batches,
                                 weights, rcfg, param_axes=axes)

@@ -305,7 +305,11 @@ def test_eval_cadence_finer_than_chunk(driver):
     # the identical state (bit-equal planes => bit-equal probes)
     assert set(per) <= set(chk)
     assert all(per[t] == chk[t] for t in per)
-    assert [r["loss"] for r in hp] == [r["loss"] for r in hc]
+    # the loss metric feeds no state, so XLA compiles its reduction apart
+    # from the round and may order it differently inside a scan (1-ulp
+    # drift); the state itself is bit-equal, as the probes above show
+    np.testing.assert_allclose([r["loss"] for r in hc],
+                               [r["loss"] for r in hp], rtol=1e-6, atol=0)
 
 
 # ---------------------------------------------------------------------------

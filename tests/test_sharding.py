@@ -3,6 +3,7 @@ import jax
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.sharding import (
     FED_MESH_RULES,
     FSDP_RULES,
@@ -19,7 +20,7 @@ from repro.sharding.rules import put_logical
 @pytest.fixture(scope="module")
 def mesh():
     n = jax.device_count()
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return make_mesh((n, 1), ("data", "model"))
 
 
 @pytest.fixture(scope="module")
@@ -28,7 +29,7 @@ def mesh16():
     # possible; use a fake mesh over available devices but with the axis
     # names used by the rules (sizes 1).
     n = jax.device_count()
-    return jax.make_mesh((1, n, 1), ("pod", "data", "model"))
+    return make_mesh((1, n, 1), ("pod", "data", "model"))
 
 
 def test_logical_spec_basic(mesh):
@@ -72,7 +73,7 @@ def test_divisibility_fallback():
 
 
 def test_fsdp_rules_shard_embed():
-    mesh = jax.make_mesh((jax.device_count(), 1), ("data", "model"))
+    mesh = make_mesh((jax.device_count(), 1), ("data", "model"))
     spec = logical_spec(("embed", "mlp"), FSDP_RULES, mesh)
     assert spec[0] in ("data", ("data",))
 
