@@ -19,7 +19,7 @@ tests/test_server_opt.py::test_fedsgd_equivalence).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, NamedTuple, Optional
+from typing import Any, Callable, Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -72,8 +72,7 @@ def fedavg(eta: float = 1.0) -> ServerOpt:
 
 
 def fedmom(eta: float = 1.0, beta: float = 0.9, *,
-           use_fused_kernel: bool = False,
-           interpret: Optional[bool] = None) -> ServerOpt:
+           use_fused_kernel: bool = False) -> ServerOpt:
     """Algorithm 3 (FedMom): Nesterov's accelerated gradient on the server.
 
         v_{t+1} = w_t - eta * delta_t
@@ -81,11 +80,10 @@ def fedmom(eta: float = 1.0, beta: float = 0.9, *,
 
     beta=0.9 everywhere in the paper's experiments.  ``use_fused_kernel``
     routes the elementwise update through the Pallas kernel
-    (kernels/fedmom_update) — one HBM pass instead of three ops.
-    ``interpret`` pins the kernel's mode for a program jitted by hand
-    (inside jit the operands are tracers, so the kernel cannot see its
-    target); ``FederatedTrainer.run`` names the platform itself, and
-    interpret mode is refused in a TPU process (``kernels/_device.py``).
+    (kernels/fedmom_update) — one HBM pass instead of three ops.  A
+    program jitted by hand traces it under ``kernel_platform(...)``
+    (``FederatedTrainer.run`` names the platform itself; see
+    ``kernels/_device.py``).
     """
     def init_extra(w):
         return {"v": jax.tree.map(jnp.copy, w)}   # v_0 = w_0
@@ -94,8 +92,7 @@ def fedmom(eta: float = 1.0, beta: float = 0.9, *,
         if use_fused_kernel:
             from repro.kernels import fedmom_ops
             w_new, v_new = fedmom_ops.fused_update_tree(
-                w, extra["v"], delta, eta=eta, beta=beta,
-                interpret=interpret)
+                w, extra["v"], delta, eta=eta, beta=beta)
             return w_new, {"v": v_new}
         v_new = _tmap(lambda wi, di: wi - eta * di, w, delta)
         w_new = _tmap(lambda vn, vo: vn + beta * (vn - vo), v_new, extra["v"])
@@ -108,21 +105,18 @@ def fedmom(eta: float = 1.0, beta: float = 0.9, *,
 # beyond-paper members of the biased-gradient family
 # ---------------------------------------------------------------------------
 def fedavgm(eta: float = 1.0, beta: float = 0.9, *,
-            use_fused_kernel: bool = False,
-            interpret: Optional[bool] = None) -> ServerOpt:
+            use_fused_kernel: bool = False) -> ServerOpt:
     """Heavy-ball (Polyak) server momentum on the biased gradient.
 
     ``use_fused_kernel`` routes the update through the fused Pallas stream
     (kernels/fedmom_update, ``kind='fedavgm'``) — one HBM pass over the
-    whole parameter tree instead of two unfused tree ops.  ``interpret``:
-    see ``fedmom``.
+    whole parameter tree instead of two unfused tree ops.
     """
     def apply(w, extra, delta, t):
         if use_fused_kernel:
             from repro.kernels import fedmom_ops
             w_new, m_new = fedmom_ops.fused_avgm_tree(
-                w, extra["m"], delta, eta=eta, beta=beta,
-                interpret=interpret)
+                w, extra["m"], delta, eta=eta, beta=beta)
             return w_new, {"m": m_new}
         m = _tmap(lambda mi, di: beta * mi + di, extra["m"], delta)
         return _tmap(lambda wi, mi: wi - eta * mi, w, m), {"m": m}

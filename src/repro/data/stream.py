@@ -411,38 +411,43 @@ class CacheView:
                            local_steps: int, batch_size: int):
         """Round ``t``'s ``[C, H, b, ...]`` batch stack, fully traceable.
 
-        Indirection happens only on the DATA fetch: a ``lax.switch`` over
-        the client's tier selects which ``[slots_t, n_tier, ...]`` corpus
-        to row-index — every branch returns the same ``[need, ...]`` shape
-        because ``idx < n_k <= n_tier`` in the client's own tier.  The
-        index draw is ``minibatch_indices(key, t, cid, n_k, need)`` with
-        the true client id — the same numbers every other plane draws.
+        Indirection happens only on the DATA fetch: every tier's
+        ``[slots_t, n_tier, ...]`` corpus is row-indexed at the client's
+        slot and a select keeps the client's own tier — every tier yields
+        the same ``[need, ...]`` shape, and ``idx < n_k <= n_tier`` in the
+        client's own tier (other tiers' reads are clamped and dropped).
+        The index draw is ``minibatch_indices(key, t, cid, n_k, need)``
+        with the true client id — the same numbers every other plane draws.
 
-        Cost note: under ``vmap`` the batched switch evaluates every
-        branch and selects, so the gather reads ``need`` rows from EACH
-        tier corpus per participant (n_tiers x the uniform gather traffic
-        for an O(H*b)-row fetch — small next to the local-step compute on
-        those same rows, and bounded by ``CacheSpec.tiers`` when a corpus
-        spans many natural power-of-two buckets).
+        This is what ``vmap`` makes of a per-client ``lax.switch`` anyway,
+        written out: a batched switch also takes the corpora it closes
+        over as batched operands, broadcast to ``[C, slots_t, ...]``.  On
+        one device XLA folds that broadcast into the gather; under a mesh
+        the partitioner splits it first and keeps it (14 GB of temporaries
+        for a 500-client LeNet corpus compiled for a v5e, over the chip's
+        16 GB).
+
+        Cost note: the gather reads ``need`` rows from EACH tier corpus per
+        participant (n_tiers x the uniform gather traffic for an
+        O(H*b)-row fetch — small next to the local-step compute on those
+        same rows, and bounded by ``CacheSpec.tiers`` when a corpus spans
+        many natural power-of-two buckets).
         """
         need = local_steps * batch_size
 
-        def rows_in(tier):
-            def branch(slot, idx):
-                return {name: a[slot][idx]
-                        for name, a in self.tier_arrays[tier].items()}
-            return branch
+        def rows_in(tier, slot, idx):
+            return {name: a[slot][idx]
+                    for name, a in self.tier_arrays[tier].items()}
 
         def one(cid):
             slot = self.client_slots[cid]
             idx = minibatch_indices(key, t, cid, self.counts[cid], need)
-            if len(self.tier_arrays) == 1:
-                rows = rows_in(0)(slot, idx)
-            else:
-                rows = jax.lax.switch(
-                    self.client_tiers[cid],
-                    [rows_in(t_) for t_ in range(len(self.tier_arrays))],
-                    slot, idx)
+            rows = rows_in(0, slot, idx)
+            for t_ in range(1, len(self.tier_arrays)):
+                other = rows_in(t_, slot, idx)
+                rows = {name: jnp.where(self.client_tiers[cid] == t_,
+                                        other[name], r)
+                        for name, r in rows.items()}
             return {
                 name: r.reshape((local_steps, batch_size) + r.shape[1:])
                 for name, r in rows.items()

@@ -2,29 +2,30 @@
 
 A Pallas TPU kernel is compiled on a TPU and interpreted on the CPU (the
 tests).  Nothing else: on a TPU it never interprets, and a kernel whose
-target cannot be told raises instead of guessing.  The target comes from,
-in order:
+target cannot be told raises instead of guessing.  The target comes from:
 
 1. the operands — a concrete array knows the device it is committed to;
 2. ``kernel_platform(...)`` — inside jit the operands are tracers, so the
    caller that knows where the program runs names it.  ``FederatedTrainer``
    enters it once per ``run()`` with the platform of the devices it places
-   on, which threads the choice into every round it traces;
-3. an explicit ``interpret=`` — for a kernel compiled by hand, e.g. for a
-   described chip in a CPU process (``tests/test_tpu_compile.py``).
+   on, which threads the choice into every round it traces; a kernel
+   compiled by hand (e.g. for a described chip in a CPU process,
+   ``tests/test_tpu_compile.py``) is traced under it too.
 
-An explicit ``interpret=`` that contradicts (1) or (2) raises, and so does
-interpret mode in a process whose default backend is a TPU.
+Concrete operands that contradict the scope raise, and so does interpret
+mode in a process whose default backend is a TPU.  Only the raw kernels
+take an explicit ``interpret=``.
 
 ``whole_on_each_device`` covers the mesh planes: the TPU compiler cannot
-partition a Mosaic kernel, so under an active mesh a kernel runs whole on
-every device, inside a ``shard_map`` with replicated operands.
+partition a Mosaic kernel, so under an active mesh the fused server update
+runs whole on every device, inside a ``shard_map`` with replicated
+operands.
 """
 from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Any, Optional
+from typing import Any
 
 import jax
 from jax.sharding import PartitionSpec as P
@@ -64,12 +65,13 @@ def _operand_platforms(operands: Any) -> set:
     return out
 
 
-def resolve_interpret(operands: Any, interpret: Optional[bool] = None) -> bool:
+def resolve_interpret(operands: Any) -> bool:
     """True when the kernel must run in interpret mode (CPU target)."""
     platforms = _operand_platforms(operands)
     if len(platforms) > 1:
         raise ValueError(
             f"kernel operands span several platforms {sorted(platforms)}")
+    scope = getattr(_scope, "platform", None)
     if platforms:
         (platform,) = platforms
         if platform == "cpu" and jax.default_backend() == "tpu":
@@ -77,20 +79,18 @@ def resolve_interpret(operands: Any, interpret: Optional[bool] = None) -> bool:
                 "kernel operands are committed to the CPU in a TPU process; "
                 "put them on the TPU (jax.device_put) — the kernel does not "
                 "fall back to interpret mode beside a chip")
-        mode = interpret_for(platform)
-    elif getattr(_scope, "platform", None) is not None:
-        mode = interpret_for(_scope.platform)
-    elif interpret is not None:
-        mode = bool(interpret)
+        if scope is not None and scope != platform:
+            raise ValueError(
+                f"kernel operands live on {platform!r}, which contradicts "
+                f"the kernel_platform({scope!r}) scope")
+    elif scope is not None:
+        platform = scope
     else:
         raise ValueError(
             "inside jit the kernel cannot see its target device: trace it "
             "under kernels._device.kernel_platform(...) (FederatedTrainer."
-            "run does) or pass interpret= explicitly")
-    if interpret is not None and bool(interpret) != mode:
-        raise ValueError(
-            f"interpret={interpret} contradicts the kernel's target "
-            f"({'cpu' if mode else 'tpu'})")
+            "run does)")
+    mode = interpret_for(platform)
     if mode and jax.default_backend() == "tpu":
         raise ValueError("interpret mode is refused in a TPU process")
     return mode

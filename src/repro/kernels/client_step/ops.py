@@ -26,14 +26,11 @@ interpreted on the CPU only.
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
 from repro.data.federated import minibatch_indices
-from repro.kernels._device import resolve_interpret, whole_on_each_device
+from repro.kernels._device import resolve_interpret
 from repro.kernels.client_step import kernel as _k
 from repro.kernels.client_step import ref as _ref
 
@@ -43,8 +40,7 @@ def _round_up(n: int, m: int) -> int:
 
 
 def client_step(xs, ys, slots, idx, w, b, lr, local_steps: int,
-                batch_size: int, step_mask=None, use_kernel: bool = True,
-                interpret: Optional[bool] = None):
+                batch_size: int, step_mask=None, use_kernel: bool = True):
     """Fused gather + H local SGD steps over one tier's C clients.
 
     Array contract of ``ref.client_step`` (see there for shapes); this
@@ -56,7 +52,7 @@ def client_step(xs, ys, slots, idx, w, b, lr, local_steps: int,
     if not use_kernel:
         return _ref.client_step(xs, ys, slots, idx, w, b, lr, local_steps,
                                 batch_size, step_mask)
-    interpret = resolve_interpret((xs, ys, w), interpret)
+    interpret = resolve_interpret((xs, ys, w))
     C = slots.shape[0]
     S, N, D = xs.shape
     H = int(local_steps)
@@ -70,16 +66,14 @@ def client_step(xs, ys, slots, idx, w, b, lr, local_steps: int,
     lr_p = jnp.reshape(jnp.asarray(lr), (1, 1)).astype(jnp.float32)
     mask = (jnp.ones((C, H), jnp.float32) if step_mask is None
             else jnp.asarray(step_mask).astype(jnp.float32))
-    launch = whole_on_each_device(functools.partial(
-        _k.client_step_flat, local_steps=H, batch_size=int(batch_size),
-        interpret=interpret))
-    wo, bo, lo = launch(xs_p, ys_p, jnp.asarray(slots, jnp.int32),
-                        jnp.asarray(idx, jnp.int32), w_p, b_p, lr_p, mask)
+    wo, bo, lo = _k.client_step_flat(
+        xs_p, ys_p, jnp.asarray(slots, jnp.int32),
+        jnp.asarray(idx, jnp.int32), w_p, b_p, lr_p, mask,
+        local_steps=H, batch_size=int(batch_size), interpret=interpret)
     return wo[:, 0, :D], bo[:, 0, 0], lo[:, 0, 0]
 
 
-def linreg_tier_step(use_kernel: bool = True,
-                     interpret: Optional[bool] = None):
+def linreg_tier_step(use_kernel: bool = True):
     """Build the ``client_step_fn`` hook ``scan_rounds_bucketed`` accepts.
 
     The hook draws the keyed minibatch indices (cheap scalar work), resolves
@@ -108,8 +102,7 @@ def linreg_tier_step(use_kernel: bool = True,
                 cids, view.counts[cids])
         wf, bf, losses = client_step(
             arrs["x"], arrs["y"], slots, idx, w_c["w"], w_c["b"], lr,
-            local_steps, batch_size, step_mask=mask, use_kernel=use_kernel,
-            interpret=interpret)
+            local_steps, batch_size, step_mask=mask, use_kernel=use_kernel)
         return {"w": wf, "b": bf}, losses
 
     return fn

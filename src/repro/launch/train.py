@@ -850,6 +850,15 @@ class FederatedTrainer:
         gather.  Same trajectory as the padded plane (bit-equal with one
         occupied tier, fp32-reduction-order tolerance across tiers)."""
         if self.client_step_fn is not None:
+            if self._mesh_spec is not None:
+                # the hook sees the whole cohort and the composed corpus:
+                # under a mesh every device would gather every tier and run
+                # every client, so the cohort would not split at all
+                raise PlanError(
+                    "client_step_fn (the fused kernels/client_step hook) "
+                    "does not split the cohort over a mesh; run it without "
+                    "plan.mesh, or drop the hook to shard the XLA local "
+                    "update", plane="streaming")
             if (self.rcfg.local_opt != "sgd"
                     or jnp.dtype(self.rcfg.compute_dtype)
                     != jnp.dtype(jnp.float32)):

@@ -163,5 +163,5 @@ def test_bucketed_fused_kernel_hook(hetero):
     ref = run_trajectory("streaming", opt, rcfg, clients, 8, hetero_fn=hf)
     got = run_trajectory(
         "streaming-bucketed", opt, rcfg, clients, 8, hetero_fn=hf,
-        client_step_fn=linreg_tier_step(use_kernel=True, interpret=True))
+        client_step_fn=linreg_tier_step(use_kernel=True))
     assert_same_trajectory(got, ref, atol=1e-5)

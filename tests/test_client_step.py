@@ -47,7 +47,7 @@ def test_kernel_matches_ref_sweep(C, H, b, D, N):
     w = jnp.asarray(rng.normal(size=D).astype(np.float32))
     bb = jnp.float32(rng.normal())
     got = cs_ops.client_step(xs, ys, slots, idx, w, bb, 0.05, H, b,
-                             use_kernel=True, interpret=True)
+                             use_kernel=True)
     want = cs_ref.client_step(xs, ys, slots, idx, w, bb, 0.05, H, b)
     for g, r in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r),
@@ -65,7 +65,7 @@ def test_kernel_matches_ref_with_masks():
     mask = jnp.asarray([[1, 1, 0, 0], [0, 0, 0, 0], [1, 1, 1, 1]],
                        jnp.float32)
     got = cs_ops.client_step(xs, ys, slots, idx, w, bb, 0.05, H, b,
-                             step_mask=mask, use_kernel=True, interpret=True)
+                             step_mask=mask, use_kernel=True)
     want = cs_ref.client_step(xs, ys, slots, idx, w, bb, 0.05, H, b,
                               step_mask=mask)
     for g, r in zip(got, want):
@@ -117,7 +117,7 @@ def test_padding_is_exact():
     w = jnp.asarray(rng.normal(size=D).astype(np.float32))
     bb = jnp.float32(0.0)
     got = cs_ops.client_step(xs, ys, slots, idx, w, bb, 0.03, H, b,
-                             use_kernel=True, interpret=True)
+                             use_kernel=True)
     want = cs_ref.client_step(xs, ys, slots, idx, w, bb, 0.03, H, b)
     assert got[0].shape == (C, D)
     for g, r in zip(got, want):

@@ -1,8 +1,9 @@
 """Device-facing rules that hold without a chip.
 
 * A Pallas kernel is compiled on a TPU and interpreted only on the CPU; its
-  target comes from concrete operands, the trainer's ``kernel_platform``
-  scope or an explicit ``interpret=``, and a kernel that cannot tell raises.
+  target comes from concrete operands or the trainer's ``kernel_platform``
+  scope, a kernel that cannot tell raises, and so do operands that
+  contradict the scope.
 * Peak device numbers are looked up by ``device_kind``; an unknown kind
   raises.
 * The compile cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, else to
@@ -55,16 +56,20 @@ def test_kernel_platform_scope_names_the_target():
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
-@pytest.mark.parametrize("where", ["operands", "scope"])
-def test_interpret_contradicting_the_target_raises(where):
-    if where == "operands":
-        with pytest.raises(ValueError, match="contradicts"):
-            _fused(_tree(0), _tree(1), _tree(2), interpret=False)
-    else:
-        with kernel_platform("cpu"), pytest.raises(ValueError,
+@pytest.mark.parametrize("where", ["eager", "nested"])
+def test_operands_contradicting_the_scope_raise(where):
+    """CPU-committed operands under a TPU scope: the kernel neither guesses
+    nor lets the scope override where the data lives."""
+    if where == "eager":
+        with kernel_platform("tpu"), pytest.raises(ValueError,
                                                    match="contradicts"):
-            jax.jit(lambda *a: _fused(*a, interpret=False))(
-                _tree(0), _tree(1), _tree(2))
+            _fused(_tree(0), _tree(1), _tree(2))
+    else:
+        with kernel_platform("cpu"):
+            with kernel_platform("tpu"), pytest.raises(ValueError,
+                                                       match="contradicts"):
+                _fused(_tree(0), _tree(1), _tree(2))
+            assert resolve_interpret(_tree()) is True
 
 
 def test_kernel_platform_rejects_other_platforms():
@@ -76,7 +81,7 @@ def test_kernel_platform_rejects_other_platforms():
 @pytest.mark.parametrize("driver", ["scanned", "device"])
 def test_trainer_threads_the_kernel_mode(driver):
     """FederatedTrainer.run names the platform for the jitted rounds, so the
-    fused server update needs no interpret= and trains the unfused
+    fused server update resolves its mode inside jit and trains the unfused
     trajectory bit for bit (interpret mode runs the same fp32 ops)."""
     clients, rcfg = make_clients(), default_rcfg()
     want = run_trajectory(driver, fedmom(eta=1.0, beta=0.9), rcfg, clients,

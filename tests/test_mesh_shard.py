@@ -28,7 +28,7 @@ import pytest
 from _trajectory import (assert_bitwise_trajectory, assert_same_trajectory,
                          default_rcfg, flat_w, make_clients, make_trainer,
                          run_driver, run_trajectory)
-from repro.core import fedmom
+from repro.core import fedavgm, fedmom
 from repro.core.secure_agg import SecureAggSpec
 from repro.data.stream import MeshShardedCache, StreamingFederatedDataset
 from repro.kernels.client_step.ops import linreg_tier_step
@@ -92,23 +92,32 @@ def test_sharded_resume_matches_straight_run(tmp_path):
 
 
 @needs_mesh
-@pytest.mark.parametrize("driver", ["device", "streaming-bucketed"])
-def test_sharded_plane_with_fused_kernels(driver):
+@pytest.mark.parametrize("kind", ["fedmom", "fedavgm"])
+def test_sharded_plane_with_fused_kernels(kind):
     """The TPU compiler cannot partition a Pallas kernel, so under a mesh
-    each fused kernel runs whole on every device: the FedMom server update
-    (device plane) and the client step (bucketed streaming) still train the
-    one-device trajectory."""
+    the fused server update runs whole on every device and still trains
+    the one-device trajectory."""
     clients = make_clients(n=N_CLIENTS)
-    def opt():
-        return fedmom(eta=1.0, beta=0.9, use_fused_kernel=driver == "device")
-
-    kw = ({} if driver == "device"
-          else {"client_step_fn": linreg_tier_step(use_kernel=True)})
-    want = run_trajectory(driver, opt(), _rcfg(), clients, 8,
-                          chunk_rounds=4, **kw)
-    got = run_trajectory(driver, opt(), _rcfg(), clients, 8,
-                         chunk_rounds=4, mesh=MESH4, **kw)
+    make = {"fedmom": fedmom, "fedavgm": fedavgm}[kind]
+    want = run_trajectory("device", make(eta=1.0, beta=0.9,
+                                         use_fused_kernel=True),
+                          _rcfg(), clients, 8, chunk_rounds=4)
+    got = run_trajectory("device", make(eta=1.0, beta=0.9,
+                                        use_fused_kernel=True),
+                         _rcfg(), clients, 8, chunk_rounds=4, mesh=MESH4)
     assert_same_trajectory(got, want, atol=1e-6)
+
+
+@needs_mesh
+def test_client_step_hook_refused_under_mesh():
+    """The fused client-step hook would gather every tier and run every
+    client on every device: under a mesh the plan is refused, not run
+    unsplit."""
+    with pytest.raises(PlanError, match="does not split the cohort"):
+        run_trajectory("streaming-bucketed", _opt(), _rcfg(),
+                       make_clients(n=N_CLIENTS), 4, chunk_rounds=4,
+                       mesh=MESH4,
+                       client_step_fn=linreg_tier_step(use_kernel=True))
 
 
 # ---------------------------------------------------------------------------

@@ -550,6 +550,46 @@ def test_tiers_knob_merges_smallest_upward():
         sds.tier_layout(tiers=0)
 
 
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                if isinstance(sub, ClosedJaxpr):
+                    yield from _eqns(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    yield from _eqns(sub)
+
+
+def test_tiered_gather_never_copies_a_corpus_per_client():
+    """The per-client tier select keeps each tier corpus an unbatched
+    operand.  A batched ``lax.switch`` broadcast every corpus to
+    ``[C, slots_t, n_tier, ...]``, which a mesh-partitioned chunk could not
+    fold away (14 GB of temporaries for LeNet's corpus on a v5e)."""
+    import jax
+    import jax.numpy as jnp
+
+    clients = [{"x": np.zeros((n, 2), np.float32)}
+               for n in (1, 1, 3, 3, 9, 9, 40, 40)]
+    sds = StreamingFederatedDataset(clients, seed=0)
+    cache = ShardCache(sds, capacity_clients=8)
+    cache.ensure(range(8))
+    view = cache.view()
+    ids = jnp.asarray([0, 2, 4, 6])
+    jaxpr = jax.make_jaxpr(lambda v: v.gather_round_batch(
+        v.base_key(), jnp.int32(0), ids, 3, 1))(view)
+    per_client = {(len(ids),) + a.shape
+                  for arrs in view.tier_arrays for a in arrs.values()}
+    assert all(a.shape[0] == 2 for arrs in view.tier_arrays
+               for a in arrs.values())                 # two slots per tier
+    shapes = {tuple(v.aval.shape) for e in _eqns(jaxpr.jaxpr)
+              for v in e.outvars if hasattr(v.aval, "shape")}
+    assert not shapes & per_client
+
+
 # ---------------------------------------------------------------------------
 # property-based packing/gather edge cases (seeded fallback when hypothesis
 # is absent — see tests/_propcheck.py)

@@ -19,6 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import RoundConfig, fedmom, round_step
+from repro.kernels._device import kernel_platform
 from repro.kernels.client_step import kernel as cs_kernel
 from repro.kernels.fedmom_update import kernel as fm_kernel
 from repro.models import small
@@ -91,16 +92,18 @@ def test_client_step_compiles(one_chip, C, S, N, H, b):
 
 def test_lenet_round_step_compiles(one_chip):
     """The jitted LeNet round (64 clients x 10 local steps of batch 10)
-    with the fused FedMom server update, as the device plane runs it."""
+    with the fused FedMom server update, traced for the chip as the device
+    plane traces it."""
     C, H, b = 64, 10, 10
-    opt = fedmom(eta=500 / C, beta=0.9, use_fused_kernel=True,
-                 interpret=False)
+    opt = fedmom(eta=500 / C, beta=0.9, use_fused_kernel=True)
     rcfg = RoundConfig(clients_per_round=C, local_steps=H, lr=0.05,
                        compute_dtype="float32")
     state = jax.tree.map(lambda s: _sds(s.shape, one_chip, s.dtype),
                          jax.eval_shape(opt.init, _lenet_shapes()))
     batches = {"x": _sds((C, H, b, 28, 28, 1), one_chip),
                "y": _sds((C, H, b), one_chip, jnp.int32)}
-    _assert_compiled_kernel(
-        lambda st, bt, w: round_step(small.lenet_loss, opt, st, bt, w, rcfg),
-        state, batches, _sds((C,), one_chip))
+    with kernel_platform("tpu"):
+        _assert_compiled_kernel(
+            lambda st, bt, w: round_step(small.lenet_loss, opt, st, bt, w,
+                                         rcfg),
+            state, batches, _sds((C,), one_chip))
