@@ -53,7 +53,8 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.round import RoundConfig, bucketed_round_step, round_step
+from repro.core.round import (GATHER, RoundConfig, bucketed_round_step,
+                              round_step)
 from repro.core.server_opt import ServerOpt, ServerState
 
 
@@ -115,7 +116,8 @@ def scan_rounds_sampled(loss_fn: Callable, server_opt: ServerOpt,
             m = None
         else:
             b, t, lr, m = xs
-        _, w = sampler.sample_device(key, t)
+        with jax.named_scope(GATHER):
+            _, w = sampler.sample_device(key, t)
         st, metrics = round_step(loss_fn, server_opt, st, b, w, rcfg,
                                  param_axes=param_axes, lr=lr, step_mask=m)
         del metrics["losses"]
@@ -158,9 +160,11 @@ def scan_rounds_ondevice(loss_fn: Callable, server_opt: ServerOpt,
             m = None
         else:
             t, lr, m = xs
-        idx, w = sampler.sample_device(sample_key, t)
-        b = dataset.gather_round_batch(data_key, t, idx, rcfg.local_steps,
-                                       local_batch_size)
+        with jax.named_scope(GATHER):
+            idx, w = sampler.sample_device(sample_key, t)
+            b = dataset.gather_round_batch(data_key, t, idx,
+                                           rcfg.local_steps,
+                                           local_batch_size)
         st, metrics = round_step(loss_fn, server_opt, st, b, w, rcfg,
                                  param_axes=param_axes, lr=lr, step_mask=m)
         del metrics["losses"]
@@ -248,14 +252,15 @@ def scan_rounds_bucketed(loss_fn: Callable, server_opt: ServerOpt,
                 ms = None
             else:
                 lr, cids, ws, idxs, ms = xs
-            parts = [
-                view.gather_tier_rows(tier, cids[i], idxs[i],
-                                      rcfg.local_steps, local_batch_size)
-                for i, tier in enumerate(tiers_present)]
-            batch = jax.tree.map(
-                lambda *ls: jnp.concatenate(ls, axis=0), *parts)
-            w = jnp.concatenate(ws, axis=0)
-            m = None if ms is None else jnp.concatenate(ms, axis=0)
+            with jax.named_scope(GATHER):
+                parts = [
+                    view.gather_tier_rows(tier, cids[i], idxs[i],
+                                          rcfg.local_steps, local_batch_size)
+                    for i, tier in enumerate(tiers_present)]
+                batch = jax.tree.map(
+                    lambda *ls: jnp.concatenate(ls, axis=0), *parts)
+                w = jnp.concatenate(ws, axis=0)
+                m = None if ms is None else jnp.concatenate(ms, axis=0)
             st, metrics = round_step(loss_fn, server_opt, st, batch,
                                      w, rcfg, param_axes=param_axes,
                                      lr=lr, step_mask=m)
@@ -274,10 +279,12 @@ def scan_rounds_bucketed(loss_fn: Callable, server_opt: ServerOpt,
         else:
             t, lr, cids, ws, ms = xs
         if client_step_fn is None:
-            data = tuple(
-                view.gather_tier_batch(tier, data_key, t, cids[i],
-                                       rcfg.local_steps, local_batch_size)
-                for i, tier in enumerate(tiers_present))
+            with jax.named_scope(GATHER):
+                data = tuple(
+                    view.gather_tier_batch(tier, data_key, t, cids[i],
+                                           rcfg.local_steps,
+                                           local_batch_size)
+                    for i, tier in enumerate(tiers_present))
             update = None
         else:
             data = cids

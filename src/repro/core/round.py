@@ -35,6 +35,16 @@ from repro.optim import local as local_opt_lib
 from repro.sharding import (client_axis_size, current_mesh, shard_tree,
                             spmd_client_axes)
 
+# Named scopes of a round's layers.  ``jax.named_scope`` is a compile-time
+# label: it lands in every op's ``op_name`` metadata, which the profiler
+# carries to the device trace, and adds no op.  Every plane runs its rounds
+# through ``round_step`` / ``bucketed_round_step`` (the gathers through
+# ``core/multiround.py``), so each layer has one name on every plane.
+GATHER = "fed.gather"          # cohort draw and its minibatch gather
+CLIENT = "fed.client"          # the clients' local updates
+AGGREGATE = "fed.aggregate"    # the weighted delta, its norm, the loss
+SERVER = "fed.server"          # the server optimizer's update
+
 
 @dataclass(frozen=True)
 class RoundConfig:
@@ -121,21 +131,24 @@ def _shard_map_round(loss_fn, opt, w_c, batches, weights, step_mask, lr,
                 loss_fn, p, b, lr_rep, opt, step_mask=m)
 
         C_s = ws.shape[0]
-        # each shard's client replicas are device-varying: left invariant,
-        # autodiff would transpose the implicit invariant->varying cast
-        # into a psum and hand every client the cross-device gradient sum
-        local0 = jax.tree.map(
-            lambda p: jax.lax.pcast(
-                jnp.broadcast_to(p[None], (C_s,) + p.shape), axes,
-                to="varying"), w_rep)
-        final, losses = (jax.vmap(one)(local0, bs) if ms is None
-                         else jax.vmap(one)(local0, bs, ms))
-        part = jax.tree.map(
-            lambda w0, wk: jnp.einsum(
-                "c,c...->...", ws, w0[None] - wk,
-                preferred_element_type=jnp.float32),
-            w_rep, final)
-        return jax.lax.psum(part, axes), losses
+        with jax.named_scope(CLIENT):
+            # each shard's client replicas are device-varying: left
+            # invariant, autodiff would transpose the implicit
+            # invariant->varying cast into a psum and hand every client the
+            # cross-device gradient sum
+            local0 = jax.tree.map(
+                lambda p: jax.lax.pcast(
+                    jnp.broadcast_to(p[None], (C_s,) + p.shape), axes,
+                    to="varying"), w_rep)
+            final, losses = (jax.vmap(one)(local0, bs) if ms is None
+                             else jax.vmap(one)(local0, bs, ms))
+        with jax.named_scope(AGGREGATE):
+            part = jax.tree.map(
+                lambda w0, wk: jnp.einsum(
+                    "c,c...->...", ws, w0[None] - wk,
+                    preferred_element_type=jnp.float32),
+                w_rep, final)
+            return jax.lax.psum(part, axes), losses
 
     rep = jax.tree.map(lambda _: P(), w_c)
     if step_mask is None:
@@ -148,7 +161,8 @@ def _shard_map_round(loss_fn, opt, w_c, batches, weights, step_mask, lr,
             body, mesh=mesh,
             in_specs=(rep, P(), lead, lead, lead), out_specs=(rep, lead))
         delta, losses = fn(w_c, lr, weights, batches, step_mask)
-    return jax.tree.map(lambda d: d.astype(ddt), delta), losses
+    with jax.named_scope(AGGREGATE):
+        return jax.tree.map(lambda d: d.astype(ddt), delta), losses
 
 
 def round_step(loss_fn, server_opt: ServerOpt, state: ServerState,
@@ -180,7 +194,8 @@ def round_step(loss_fn, server_opt: ServerOpt, state: ServerState,
             "cannot even hold the [C, ...] cohort stack")
     opt = local_opt_lib.get(rcfg.local_opt, **dict(rcfg.local_opt_kwargs))
     lr = jnp.asarray(rcfg.lr if lr is None else lr, jnp.float32)
-    w_c = _cast_tree(state.w, jnp.dtype(rcfg.compute_dtype))
+    with jax.named_scope(CLIENT):
+        w_c = _cast_tree(state.w, jnp.dtype(rcfg.compute_dtype))
     ddt = jnp.dtype(rcfg.delta_dtype)
 
     def one_client(p, b, m=None):
@@ -203,33 +218,37 @@ def round_step(loss_fn, server_opt: ServerOpt, state: ServerState,
                 loss_fn, opt, w_c, batches, weights, step_mask, lr,
                 mesh, axes, ddt)
         else:
-            local0 = jax.tree.map(
-                lambda p: jnp.broadcast_to(p[None], (C,) + p.shape), w_c)
-            if param_axes is not None:
-                local0 = shard_tree(local0, param_axes, prefix=("clients",))
-            spmd = spmd_client_axes()
-            vmapped = jax.vmap(one_client, spmd_axis_name=spmd) if spmd \
-                else jax.vmap(one_client)
-            if step_mask is None:
-                final, losses = vmapped(local0, batches)
-            else:
-                final, losses = vmapped(local0, batches, step_mask)
-            if param_axes is not None:
-                final = shard_tree(final, param_axes, prefix=("clients",))
+            with jax.named_scope(CLIENT):
+                local0 = jax.tree.map(
+                    lambda p: jnp.broadcast_to(p[None], (C,) + p.shape), w_c)
+                if param_axes is not None:
+                    local0 = shard_tree(local0, param_axes,
+                                        prefix=("clients",))
+                spmd = spmd_client_axes()
+                vmapped = jax.vmap(one_client, spmd_axis_name=spmd) if spmd \
+                    else jax.vmap(one_client)
+                if step_mask is None:
+                    final, losses = vmapped(local0, batches)
+                else:
+                    final, losses = vmapped(local0, batches, step_mask)
+                if param_axes is not None:
+                    final = shard_tree(final, param_axes,
+                                       prefix=("clients",))
             # products and accumulation stay fp32 no matter delta_dtype:
             # rounding the n_k/n weights (or the per-client diffs) to bf16
             # BEFORE the reduction leaks weight mass under skewed n_k; only
             # the final result is rounded to ddt, so the bf16 delta is the
             # correctly-rounded fp32 reduction
-            if rcfg.secure is not None:
-                delta = _secure_delta(rcfg.secure, w_c, final, weights,
-                                      step_mask, state.t, ddt)
-            else:
-                delta = jax.tree.map(
-                    lambda w0, wk: jnp.einsum(
-                        "c,c...->...", weights, w0[None] - wk,
-                        preferred_element_type=jnp.float32).astype(ddt),
-                    w_c, final)
+            with jax.named_scope(AGGREGATE):
+                if rcfg.secure is not None:
+                    delta = _secure_delta(rcfg.secure, w_c, final, weights,
+                                          step_mask, state.t, ddt)
+                else:
+                    delta = jax.tree.map(
+                        lambda w0, wk: jnp.einsum(
+                            "c,c...->...", weights, w0[None] - wk,
+                            preferred_element_type=jnp.float32).astype(ddt),
+                        w_c, final)
     elif rcfg.placement == "scan":
         if param_axes is not None:
             # scan placement promises FSDP-sharded params: constrain the
@@ -244,40 +263,48 @@ def round_step(loss_fn, server_opt: ServerOpt, state: ServerState,
                 m_k = None
             else:
                 b_k, a_k, m_k = xs
-            wk, loss = one_client(w_c, b_k, m_k)
+            with jax.named_scope(CLIENT):
+                wk, loss = one_client(w_c, b_k, m_k)
             # fp32 accumulator to match the mesh-path einsum; cast to ddt
             # once after the scan
-            acc = jax.tree.map(
-                lambda d, w0, wkl: d + a_k
-                * (w0 - wkl).astype(jnp.float32),
-                acc, w_c, wk)
-            if param_axes is not None:
-                acc = shard_tree(acc, param_axes)
+            with jax.named_scope(AGGREGATE):
+                acc = jax.tree.map(
+                    lambda d, w0, wkl: d + a_k
+                    * (w0 - wkl).astype(jnp.float32),
+                    acc, w_c, wk)
+                if param_axes is not None:
+                    acc = shard_tree(acc, param_axes)
             return acc, loss
-        delta0 = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), w_c)
+        with jax.named_scope(AGGREGATE):
+            delta0 = jax.tree.map(
+                lambda x: jnp.zeros(x.shape, jnp.float32), w_c)
         xs = ((batches, weights) if step_mask is None
               else (batches, weights, step_mask))
         delta, losses = jax.lax.scan(body, delta0, xs)
-        delta = jax.tree.map(lambda d: d.astype(ddt), delta)
+        with jax.named_scope(AGGREGATE):
+            delta = jax.tree.map(lambda d: d.astype(ddt), delta)
     else:
         raise ValueError(rcfg.placement)
 
-    new_state = server_opt.update(state, delta)
-    eff_w = weights
-    if step_mask is not None:
-        eff_w = weights * (jnp.sum(step_mask, axis=1) > 0)
-    wsum = jnp.maximum(jnp.sum(eff_w), 1e-12)
-    metrics = {
-        "loss": jnp.sum(eff_w * losses) / wsum,
-        "losses": losses,
-        "delta_norm": _global_norm(delta),
-        # clients that contributed work to eq. (3): positive weight AND >=1
-        # unmasked local step — dropouts/stragglers that finished nothing
-        # and zero-weight padded slots both fall out, so a scenario run's
-        # per-round completion is observable from the metrics stream
-        "completed": jnp.sum(eff_w > 0).astype(jnp.int32),
-        "round": state.t,
-    }
+    with jax.named_scope(SERVER):
+        new_state = server_opt.update(state, delta)
+    with jax.named_scope(AGGREGATE):
+        eff_w = weights
+        if step_mask is not None:
+            eff_w = weights * (jnp.sum(step_mask, axis=1) > 0)
+        wsum = jnp.maximum(jnp.sum(eff_w), 1e-12)
+        metrics = {
+            "loss": jnp.sum(eff_w * losses) / wsum,
+            "losses": losses,
+            "delta_norm": _global_norm(delta),
+            # clients that contributed work to eq. (3): positive weight AND
+            # >=1 unmasked local step — dropouts/stragglers that finished
+            # nothing and zero-weight padded slots both fall out, so a
+            # scenario run's per-round completion is observable from the
+            # metrics stream
+            "completed": jnp.sum(eff_w > 0).astype(jnp.int32),
+            "round": state.t,
+        }
     return new_state, metrics
 
 
@@ -324,7 +351,8 @@ def bucketed_round_step(loss_fn, server_opt: ServerOpt, state: ServerState,
             f"(got {rcfg.placement!r}); use the padded round_step for scan")
     opt = local_opt_lib.get(rcfg.local_opt, **dict(rcfg.local_opt_kwargs))
     lr = jnp.asarray(rcfg.lr if lr is None else lr, jnp.float32)
-    w_c = _cast_tree(state.w, jnp.dtype(rcfg.compute_dtype))
+    with jax.named_scope(CLIENT):
+        w_c = _cast_tree(state.w, jnp.dtype(rcfg.compute_dtype))
     ddt = jnp.dtype(rcfg.delta_dtype)
 
     def one_client(p, b, m=None):
@@ -347,50 +375,57 @@ def bucketed_round_step(loss_fn, server_opt: ServerOpt, state: ServerState,
 
     update = tier_update_fn or run_tier
     secure = rcfg.secure
-    if secure is not None:
-        acc = jax.tree.map(
-            lambda x: jnp.zeros(x.shape, jnp.uint32), w_c)
-        round_key = (secure_agg.round_mask_key(secure, state.t)
-                     if secure.masked else None)
-    else:
-        acc = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), w_c)
-    loss_num = jnp.zeros((), jnp.float32)
-    loss_den = jnp.zeros((), jnp.float32)
-    completed = jnp.zeros((), jnp.int32)
-    for i, (data, weights) in enumerate(zip(tier_data, tier_weights)):
-        mask = None if tier_masks is None else tier_masks[i]
-        final, losses = update(w_c, i, data, mask)
+    with jax.named_scope(AGGREGATE):
         if secure is not None:
-            tier_key = (jax.random.fold_in(round_key, i)
-                        if secure.masked else None)
-            y = _weighted_delta_stack(w_c, final, weights)
-            ring = secure_agg.masked_ring_sum(
-                y, _survivors(mask), secure, tier_key)
-            acc = jax.tree.map(lambda a, r: a + r, acc, ring)
+            acc = jax.tree.map(
+                lambda x: jnp.zeros(x.shape, jnp.uint32), w_c)
+            round_key = (secure_agg.round_mask_key(secure, state.t)
+                         if secure.masked else None)
         else:
             acc = jax.tree.map(
-                lambda d, w0, wk: d + jnp.einsum(
-                    "c,c...->...", weights, w0[None] - wk,
-                    preferred_element_type=jnp.float32),
-                acc, w_c, final)
-        eff_w = weights
-        if mask is not None:
-            eff_w = weights * (jnp.sum(mask, axis=1) > 0)
-        loss_num = loss_num + jnp.sum(eff_w * losses)
-        loss_den = loss_den + jnp.sum(eff_w)
-        completed = completed + jnp.sum(eff_w > 0).astype(jnp.int32)
-    if secure is not None:
-        delta = jax.tree.map(
-            lambda d: d.astype(ddt), secure_agg.decode(acc, secure))
-    else:
-        delta = jax.tree.map(lambda d: d.astype(ddt), acc)
-    new_state = server_opt.update(state, delta)
-    metrics = {
-        "loss": loss_num / jnp.maximum(loss_den, 1e-12),
-        "delta_norm": _global_norm(delta),
-        "completed": completed,
-        "round": state.t,
-    }
+                lambda x: jnp.zeros(x.shape, jnp.float32), w_c)
+        loss_num = jnp.zeros((), jnp.float32)
+        loss_den = jnp.zeros((), jnp.float32)
+        completed = jnp.zeros((), jnp.int32)
+    for i, (data, weights) in enumerate(zip(tier_data, tier_weights)):
+        mask = None if tier_masks is None else tier_masks[i]
+        with jax.named_scope(CLIENT):
+            final, losses = update(w_c, i, data, mask)
+        with jax.named_scope(AGGREGATE):
+            if secure is not None:
+                tier_key = (jax.random.fold_in(round_key, i)
+                            if secure.masked else None)
+                y = _weighted_delta_stack(w_c, final, weights)
+                ring = secure_agg.masked_ring_sum(
+                    y, _survivors(mask), secure, tier_key)
+                acc = jax.tree.map(lambda a, r: a + r, acc, ring)
+            else:
+                acc = jax.tree.map(
+                    lambda d, w0, wk: d + jnp.einsum(
+                        "c,c...->...", weights, w0[None] - wk,
+                        preferred_element_type=jnp.float32),
+                    acc, w_c, final)
+            eff_w = weights
+            if mask is not None:
+                eff_w = weights * (jnp.sum(mask, axis=1) > 0)
+            loss_num = loss_num + jnp.sum(eff_w * losses)
+            loss_den = loss_den + jnp.sum(eff_w)
+            completed = completed + jnp.sum(eff_w > 0).astype(jnp.int32)
+    with jax.named_scope(AGGREGATE):
+        if secure is not None:
+            delta = jax.tree.map(
+                lambda d: d.astype(ddt), secure_agg.decode(acc, secure))
+        else:
+            delta = jax.tree.map(lambda d: d.astype(ddt), acc)
+    with jax.named_scope(SERVER):
+        new_state = server_opt.update(state, delta)
+    with jax.named_scope(AGGREGATE):
+        metrics = {
+            "loss": loss_num / jnp.maximum(loss_den, 1e-12),
+            "delta_norm": _global_norm(delta),
+            "completed": completed,
+            "round": state.t,
+        }
     return new_state, metrics
 
 

@@ -631,10 +631,25 @@ class FederatedTrainer:
         data/device.py for the K * n_max memory ceiling this implies).
         Keyed by the active mesh spec: packing places the client axis
         under the live mesh context, so a sharded and an unsharded run
-        never share a packed corpus."""
-        return self.session.device_dataset(self.dataset,
-                                           shard_clients=shard_clients,
-                                           mesh=self._mesh_spec)
+        never share a packed corpus.
+
+        A call that packs appends one ``{"event": "pack", "seconds",
+        "bytes", "clients"}`` record to the history, like the ``plan``
+        event: ``seconds`` on the host clock covers filling the padded
+        arrays and handing them to ``device_put``, not the transfer's end.
+        Waiting for the transfer would serialize it with the first chunk's
+        compile and could lengthen set-up."""
+        before = self.session.device_ds
+        t = time.perf_counter()
+        dds = self.session.device_dataset(self.dataset,
+                                          shard_clients=shard_clients,
+                                          mesh=self._mesh_spec)
+        if dds is not before and dds is not self.dataset:
+            self.history.append({"event": "pack",
+                                 "seconds": time.perf_counter() - t,
+                                 "bytes": dds.nbytes,
+                                 "clients": dds.n_clients})
+        return dds
 
     def _sample_key(self):
         return (self.sampler.base_key()
@@ -695,12 +710,9 @@ class FederatedTrainer:
             cache.ensure(parts)
             return cache.view()
 
-        stats0 = _cache_counters(cache)
-        view = upload(prepare(0)) if spans else None
         return self._run_fused_chunks(
-            spans, n_rounds, view, sds.base_key(), prepare, upload,
-            prefetch, eval_fn=eval_fn, verbose=verbose, cache=cache,
-            cache_stats0=stats0)
+            spans, n_rounds, None, sds.base_key(), prepare, upload,
+            prefetch, eval_fn=eval_fn, verbose=verbose, cache=cache)
 
     # ------------------------------------------------------------------
     # plane: streaming + cache.bucketed — n_k-shaped per-tier dispatch
@@ -898,27 +910,26 @@ class FederatedTrainer:
                 args += (jax.tree.map(jnp.asarray, ms),)
             return fn(*args)
 
-        stats0 = _cache_counters(cache)
-        view = upload(prepare(0)) if spans else None
         return self._run_fused_chunks(
-            spans, n_rounds, view, data_key, prepare, upload, prefetch,
+            spans, n_rounds, None, data_key, prepare, upload, prefetch,
             eval_fn=eval_fn, verbose=verbose, cache=cache,
-            cache_stats0=stats0, dispatch=dispatch)
+            dispatch=dispatch)
 
     # ------------------------------------------------------------------
     # the chunk loop shared by the fused on-device planes
     # ------------------------------------------------------------------
     def _run_fused_chunks(self, spans, n_rounds, view, data_key,
                           prepare, upload, prefetch, eval_fn, verbose,
-                          cache=None, cache_stats0=None, dispatch=None):
+                          cache=None, dispatch=None):
         """Per-chunk knobs, one dispatch, shared bookkeeping for the device
-        and streaming planes.  ``view`` is the gather-contract pytree for
-        the first span; with staging hooks, ``prepare(i)`` does the
-        host-side lookahead for span i (called BEFORE span i-1's dispatch,
-        so its eager replay ops never queue behind the in-flight chunk) and
-        ``upload(prepared)`` makes span i's data resident and returns its
-        view — dispatched right after the chunk when ``prefetch``
-        (overlapping its compute), after the metrics sync otherwise.
+        and streaming planes.  ``view`` is the gather-contract pytree of
+        every span (the device plane); with staging hooks it is None, and
+        ``prepare(i)`` does the host-side lookahead for span i (called
+        BEFORE span i-1's dispatch, so its eager replay ops never queue
+        behind the in-flight chunk) and ``upload(prepared)`` makes span
+        i's data resident and returns its view — dispatched right after
+        the chunk when ``prefetch`` (overlapping its compute), after the
+        metrics sync otherwise.
         ``dispatch(i, s, e, view) -> (state, metrics)`` overrides the
         default ondevice-chunk launch (the bucketed plane supplies its own
         staged per-tier launch); it must donate/consume ``self.state``
@@ -929,11 +940,30 @@ class FederatedTrainer:
         overlapped with compute); chunk-boundary eval and the async
         checkpoint snapshot still run *before* the next dispatch donates the
         chunk's state.  Per-chunk ``ShardCache`` counter deltas ride on each
-        chunk's last metrics record (history + jsonl)."""
+        chunk's last metrics record (history + jsonl).
+
+        Host spans on the profiler's clock (``jax.profiler.TraceAnnotation``,
+        about a microsecond each while no trace runs), each with a
+        ``chunk`` stat naming the first round of the chunk it serves:
+        ``fed.stage`` (``prepare``), ``fed.upload`` (``upload``),
+        ``fed.dispatch`` (the jitted chunk call; a compile shows as a long
+        one), ``fed.seal`` and ``fed.drain`` (``_seal_chunk`` /
+        ``_drain_chunk``)."""
+        def stage(i):
+            with jax.profiler.TraceAnnotation("fed.stage",
+                                              chunk=spans[i][0]):
+                return prepare(i)
+
+        def put(i, prepared):
+            with jax.profiler.TraceAnnotation("fed.upload",
+                                              chunk=spans[i][0]):
+                return upload(prepared)
+
         sample_key = self._sample_key()
         t_start = time.time()
-        stats0 = cache_stats0 if cache_stats0 is not None \
-            else _cache_counters(cache)
+        stats0 = _cache_counters(cache)
+        if prepare is not None and spans:
+            view = put(0, stage(0))
         pending = None        # chunk dispatched but not yet drained
                               # (last element: sealed yet?)
         with self._writer() as writer:
@@ -942,7 +972,7 @@ class FederatedTrainer:
                     if dispatch is None:
                         lrs, masks = self._chunk_knobs(s, e)
                         fn = self._device_chunk_fn(e - s, masks is not None)
-                    nxt = (prepare(i + 1)
+                    nxt = (stage(i + 1)
                            if prepare and i + 1 < len(spans) else None)
                     if pending is not None:
                         # the previous chunk's state is live only until
@@ -950,20 +980,22 @@ class FederatedTrainer:
                         # now, the blocking metrics sync after the dispatch
                         pending = self._seal_chunk(pending, n_rounds,
                                                    eval_fn, writer)
-                    if dispatch is None:
-                        args = (self.state, view, sample_key, data_key,
-                                jnp.int32(s), jnp.asarray(lrs))
-                        if masks is not None:
-                            args += (jnp.asarray(masks),)
-                        self.state, metrics = fn(*args)  # async dispatch
-                    else:
-                        self.state, metrics = dispatch(i, s, e, view)
+                    with jax.profiler.TraceAnnotation("fed.dispatch",
+                                                      chunk=s):
+                        if dispatch is None:
+                            args = (self.state, view, sample_key, data_key,
+                                    jnp.int32(s), jnp.asarray(lrs))
+                            if masks is not None:
+                                args += (jnp.asarray(masks),)
+                            self.state, metrics = fn(*args)  # async
+                        else:
+                            self.state, metrics = dispatch(i, s, e, view)
                     if nxt is not None and prefetch:
                         # double-buffered staging: span i+1's H2D scatters
                         # are dispatched now and overlap chunk i's scanned
                         # compute; chunk i's view snapshot stays valid
                         # (functional updates never touch captured arrays)
-                        view = upload(nxt)
+                        view = put(i + 1, nxt)
                     if pending is not None:
                         done, pending = pending, None
                         self._drain_chunk(done, verbose, t_start,
@@ -981,7 +1013,7 @@ class FederatedTrainer:
                                                    eval_fn, writer)
                         done, pending = pending, None
                         self._drain_chunk(done, verbose, t_start, writer)
-                        view = upload(nxt)
+                        view = put(i + 1, nxt)
                 if pending is not None:
                     pending = self._seal_chunk(pending, n_rounds, eval_fn,
                                                writer)
@@ -1025,14 +1057,15 @@ class FederatedTrainer:
         inside the chunk, plus one final save so a chunked run always ends
         restorable."""
         s, e, metrics, cstats, _, _, _ = pending
-        ev = eval_fn(self.state) if eval_fn is not None else None
-        due = self.ckpt_every and any(
-            t > 0 and t % self.ckpt_every == 0 for t in range(s, e))
-        snap = None
-        if writer and (due or e == n_rounds):
-            # async device copy, dispatched before the next chunk's
-            # donation invalidates these buffers
-            snap = jax.tree.map(jnp.copy, self.state)
+        with jax.profiler.TraceAnnotation("fed.seal", chunk=s):
+            ev = eval_fn(self.state) if eval_fn is not None else None
+            due = self.ckpt_every and any(
+                t > 0 and t % self.ckpt_every == 0 for t in range(s, e))
+            snap = None
+            if writer and (due or e == n_rounds):
+                # async device copy, dispatched before the next chunk's
+                # donation invalidates these buffers
+                snap = jax.tree.map(jnp.copy, self.state)
         return (s, e, metrics, cstats, ev, snap, True)
 
     def _drain_chunk(self, pending, verbose: bool, t_start: float,
@@ -1041,30 +1074,31 @@ class FederatedTrainer:
         jsonl append, progress line, then the checkpoint submit (after the
         append — see ``_seal_chunk``)."""
         s, e, metrics, cstats, ev, snap, _ = pending
-        losses = np.asarray(metrics["loss"])  # one sync per chunk
-        dnorms = np.asarray(metrics["delta_norm"])
-        recs = [{"round": t, "loss": float(losses[i]),
-                 "delta_norm": float(dnorms[i])}
-                for i, t in enumerate(range(s, e))]
-        if self._scenario is not None and "completed" in metrics:
-            done = np.asarray(metrics["completed"])
-            for i, rec in enumerate(recs):
-                rec["completed"] = int(done[i])
-        if ev is not None:
-            recs[-1].update(ev)
-        if cstats is not None:
-            recs[-1].update(cstats)
-        self.history.extend(recs)
-        if self.metrics_path:
-            append_metrics(self.metrics_path, recs)
-        if verbose:
-            print(f"  rounds {s:5d}..{e - 1:5d}  "
-                  f"loss={recs[-1]['loss']:.4f} "
-                  f"delta_norm={recs[-1]['delta_norm']:.4f}  "
-                  f"({time.time() - t_start:.1f}s)")
-        if writer and snap is not None:
-            writer.submit(self.ckpt_path, snap, {"round": e - 1},
-                          copy=False)
+        with jax.profiler.TraceAnnotation("fed.drain", chunk=s):
+            losses = np.asarray(metrics["loss"])  # one sync per chunk
+            dnorms = np.asarray(metrics["delta_norm"])
+            recs = [{"round": t, "loss": float(losses[i]),
+                     "delta_norm": float(dnorms[i])}
+                    for i, t in enumerate(range(s, e))]
+            if self._scenario is not None and "completed" in metrics:
+                done = np.asarray(metrics["completed"])
+                for i, rec in enumerate(recs):
+                    rec["completed"] = int(done[i])
+            if ev is not None:
+                recs[-1].update(ev)
+            if cstats is not None:
+                recs[-1].update(cstats)
+            self.history.extend(recs)
+            if self.metrics_path:
+                append_metrics(self.metrics_path, recs)
+            if verbose:
+                print(f"  rounds {s:5d}..{e - 1:5d}  "
+                      f"loss={recs[-1]['loss']:.4f} "
+                      f"delta_norm={recs[-1]['delta_norm']:.4f}  "
+                      f"({time.time() - t_start:.1f}s)")
+            if writer and snap is not None:
+                writer.submit(self.ckpt_path, snap, {"round": e - 1},
+                              copy=False)
 
     def _finish_chunk(self, s: int, e: int, n_rounds: int, metrics,
                       eval_fn, verbose: bool,
