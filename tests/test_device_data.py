@@ -3,6 +3,7 @@ bit-equality of the in-scan minibatch gather with the host keyed assembly,
 trajectory equivalence of the device-resident tier (via the shared
 tests/_trajectory.py harness), and the async checkpoint writer."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -76,21 +77,90 @@ def test_pack_rejects_ragged_fields():
 # ---------------------------------------------------------------------------
 # host/device gather equivalence (the bit-replay contract)
 # ---------------------------------------------------------------------------
-def test_gather_round_batch_bit_equals_host_assembly():
+def _image_clients(rng, sizes):
+    return [{"x": rng.normal(size=(n, 28, 28, 1)).astype(np.float32),
+             "y": rng.integers(0, 62, size=n).astype(np.int32)}
+            for n in sizes]
+
+
+def _token_clients(rng, sizes):
+    return [{"tokens": rng.integers(0, 90, size=(n, 80)).astype(np.int32),
+             "labels": rng.integers(0, 90, size=(n, 80)).astype(np.int32)}
+            for n in sizes]
+
+
+def _gather_case(case):
+    """(clients, cohort size, mesh or None) for one bit-equality case."""
+    rng = np.random.default_rng(7)
+    if case == "vector":
+        return make_clients(seed=7), 3, None
+    if case == "image":
+        return _image_clients(rng, (23, 31, 17, 26, 35, 20)), 3, None
+    if case == "tokens":
+        return _token_clients(rng, (23, 31, 17, 26, 35, 20)), 3, None
+    if case == "small_client":          # n_0 = 5 < H*b = 12, every round
+        return _image_clients(rng, (5, 31, 17, 26)), 4, None
+    from repro.launch.mesh import MeshSpec
+    return make_clients(seed=7, n=8), 4, MeshSpec(devices=4).build()
+
+
+@pytest.mark.parametrize("case", ["vector", "image", "tokens",
+                                  "small_client", "mesh4"])
+def test_gather_round_batch_bit_equals_host_assembly(case):
     from repro.core import DeviceUniformSampler
-    clients = make_clients(seed=7)
+    from repro.sharding import FED_MESH_RULES, axis_rules
+    clients, C, mesh = _gather_case(case)
     ds = FederatedDataset([dict(c) for c in clients], seed=1)
-    dds = DeviceFederatedDataset.from_federated(ds)
-    sampler = DeviceUniformSampler(ds.population(), 3, seed=2)
+    if mesh is None:
+        dds = DeviceFederatedDataset.from_federated(ds)
+    else:
+        with axis_rules(mesh, FED_MESH_RULES):
+            dds = DeviceFederatedDataset.from_federated(ds)
+        for leaf in dds.arrays.values():     # the client axis is split
+            shards = leaf.addressable_shards
+            assert len(shards) == 4
+            assert all(sh.data.shape[0] * 4 == leaf.shape[0]
+                       for sh in shards)
+    sampler = DeviceUniformSampler(ds.population(), C, seed=2)
     gather = jax.jit(
-        lambda key, t, ids: dds.gather_round_batch(key, t, ids, 4, 3))
+        lambda d, key, t, ids: d.gather_round_batch(key, t, ids, 4, 3))
     for t in range(25):
         idx, _ = sampler.sample(t)
         host = ds.round_batches(idx, 4, 3, t=t)
-        dev = gather(dds.base_key(), jnp.int32(t), jnp.asarray(idx))
+        dev = gather(dds, dds.base_key(), jnp.int32(t), jnp.asarray(idx))
+        assert set(dev) == set(host)
         for name in host:
+            assert dev[name].dtype == host[name].dtype
             np.testing.assert_array_equal(host[name],
                                           np.asarray(dev[name]))
+
+
+_GATHER = re.compile(r'"?stablehlo\.gather"?.*slice_sizes = array<i64: '
+                     r'([0-9, ]+)>.*: \(tensor<([0-9x]+)x[a-z]')
+
+
+@pytest.mark.parametrize("make", [_image_clients, _token_clients],
+                         ids=["image_f32", "tokens_i32"])
+def test_gather_reads_only_drawn_rows(make):
+    """The lowered round gather slices single (client, row) samples out of
+    the corpus: no gather on a corpus leaf takes more than one row, so a
+    round reads C*H*b rows, never a client's whole padded slab."""
+    clients = make(np.random.default_rng(3), (7, 19, 12, 16, 9))
+    dds = DeviceFederatedDataset.pack(clients, seed=0)
+    K, n_max = dds.n_clients, dds.n_max
+    text = jax.jit(
+        lambda d, key, t, ids: d.gather_round_batch(key, t, ids, 4, 3)
+    ).lower(dds, dds.base_key(), jnp.int32(0), jnp.arange(3)).as_text()
+    corpus = []
+    for m in map(_GATHER.search, text.splitlines()):
+        if m is None:
+            continue
+        sizes = [int(v) for v in m.group(1).split(",")]
+        dims = [int(v) for v in m.group(2).split("x")]
+        if dims[:2] == [K, n_max]:
+            corpus.append(sizes)
+    assert len(corpus) == len(dds.arrays)         # one gather per leaf
+    assert all(s[:2] == [1, 1] for s in corpus), corpus
 
 
 def test_gather_with_replacement_small_client():
