@@ -15,8 +15,10 @@ Set-up (everything before the first timed round, and ``setup_s``):
 
 The window is one ``run(n)`` on the same trainer, rounds back to back, on
 cohorts drawn from a seed of their own (new clients, not the warm-up's).
-With ``--trace 1`` a short stretch after it runs under the profiler.  Then
-the program's state is freed and the reference follows the first chunk.
+With ``--trace 1`` a short stretch after it runs under the profiler, and
+its trace is split by the program's named scopes (``chipbench.scopes``).
+Then the program's state is freed and the reference follows the first
+chunk.
 """
 from __future__ import annotations
 
@@ -33,7 +35,7 @@ from typing import List, Optional
 
 import numpy as np
 
-from chipbench import parts, reference, registry
+from chipbench import parts, reference, registry, scopes
 from chipbench import trace as trace_lib
 from chipbench.peaks import Peaks, peaks
 from chipbench.population import Seeds
@@ -100,7 +102,10 @@ class Cell:
 
 @dataclass
 class Context:
-    """What a metric reader sees (``metrics/<name>.py``: ``read(ctx)``)."""
+    """What a metric reader sees (``metrics/<name>.py``: ``read(ctx)``).
+    Traced, ``layers`` is the stretch's split by named scope and ``trace``
+    its ``base``; untraced, both are None.  ``events`` are the program's
+    event records of the first chunk (``{"event": "pack", ...}``)."""
     cell: str
     config: dict
     traffic: dict
@@ -111,7 +116,9 @@ class Context:
     window_s: float
     window_rounds: int
     window_history: List[dict]
+    events: List[dict]
     trace: Optional[trace_lib.Reduction] = None
+    layers: Optional[scopes.Layers] = None
 
 
 def configure_jax():
@@ -196,6 +203,7 @@ class FirstChunk:
     counts: np.ndarray
     seeds: Seeds
     program: dict        # "loss", "dnorm" per round; "w0", "w" (host)
+    events: List[dict]   # the program's event records, pack among them
 
 
 def checked_first_chunk(cell: Cell, seed: int, log) -> FirstChunk:
@@ -226,7 +234,8 @@ def checked_first_chunk(cell: Cell, seed: int, log) -> FirstChunk:
     program = {"loss": [r["loss"] for r in hist],
                "dnorm": [r["delta_norm"] for r in hist],
                "w0": w0, "w": _host(tr.state.w)}
-    return FirstChunk(tr, plan, clients, counts, seeds, program)
+    events = [r for r in tr.history if "event" in r]
+    return FirstChunk(tr, plan, clients, counts, seeds, program, events)
 
 
 def run_cell(cell: Cell, bench: dict, seed: int, seconds: float,
@@ -261,12 +270,12 @@ def run_cell(cell: Cell, bench: dict, seed: int, seconds: float,
           flush=True)
     failed = sum(not math.isfinite(r["loss"]) for r in hist)
 
-    reduction = None
+    layers = None
     if trace:
         n_trace = R * max(1, math.ceil(min(seconds, TRACE_SECONDS)
                                        / (per_round * R)))
         tr.sampler.seed = seeds.trace
-        reduction = _traced(tr, n_trace, plan, [d.id for d in used], log)
+        layers = _traced(tr, n_trace, plan, [d.id for d in used], log)
 
     peak = max(d.memory_stats()["peak_bytes_in_use"] for d in used) \
         if require_chip else 0
@@ -277,7 +286,8 @@ def run_cell(cell: Cell, bench: dict, seed: int, seconds: float,
     t = time.perf_counter()
     ref = reference.follow(cell, first.clients, first.counts, seeds,
                            rounds=R, shards=cell.chips)
-    log(f"reference ({R} rounds): {time.perf_counter() - t:.3f} s")
+    log(f"reference ({R} rounds, blocks of {ref['block']} clients): "
+        f"{time.perf_counter() - t:.3f} s")
     numbers = reference.gaps(first.program, ref, cell.limits["loss_rounds"])
     log(f"numbers, compared or not: {json.dumps(numbers)}")
     lim = cell.limits["limits"]
@@ -289,7 +299,8 @@ def run_cell(cell: Cell, bench: dict, seed: int, seconds: float,
                   peaks=peaks(dev.device_kind) if require_chip else None,
                   setup_s=setup_s, window_s=window_s,
                   window_rounds=n_window, window_history=hist,
-                  trace=reduction)
+                  events=first.events,
+                  trace=layers.base if layers else None, layers=layers)
     kind = "per_layer" if trace else "end_to_end"
     metrics = {}
     for m in registry.metrics_for(cell.name, bench, kind):
@@ -300,7 +311,8 @@ def run_cell(cell: Cell, bench: dict, seed: int, seconds: float,
               "count": len(devices), "memory_peak_bytes": peak}
     result = {"correct": bool(correct), "attempted": n_window,
               "failed": failed, "metrics": metrics, "device": device}
-    if reduction is not None:
+    if layers is not None:
+        reduction = layers.base
         device["busy_s"] = reduction.mean_busy_s
         device["window_s"] = reduction.window_s
         top = sorted(reduction.op_s.items(), key=lambda kv: -kv[1])[:10]
@@ -314,8 +326,8 @@ def run_cell(cell: Cell, bench: dict, seed: int, seconds: float,
     return result, lines
 
 
-def _traced(tr, n: int, plan, device_ids, log):
-    """``run(n)`` under the profiler; its reduction."""
+def _traced(tr, n: int, plan, device_ids, log) -> scopes.Layers:
+    """``run(n)`` under the profiler; its split by named scope."""
     import jax
 
     trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
@@ -327,11 +339,12 @@ def _traced(tr, n: int, plan, device_ids, log):
         finally:
             jax.profiler.stop_trace()
         t = time.perf_counter()
-        data = trace_lib.load(trace_lib.find_xplane(trace_dir))
-        red = trace_lib.reduce(data, devices=device_ids)
+        data = scopes.load(trace_lib.find_xplane(trace_dir))
+        layers = scopes.reduce(data, rounds=n, devices=device_ids)
         log(f"trace of {n} rounds read in {time.perf_counter() - t:.3f} s: "
-            f"window {red.window_s:.6f} s, busy {red.busy_s}")
-        return red
+            f"window {layers.base.window_s:.6f} s, busy "
+            f"{layers.base.busy_s}, ms per round {layers.ms_per_round()}")
+        return layers
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
 

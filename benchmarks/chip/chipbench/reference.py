@@ -10,9 +10,15 @@ cell's own (``chipbench.parts``), so the reference follows whatever the
 configuration names.
 
 It imports nothing of the program and takes nothing the program made (it
-builds the initial weights itself from the seed).  A round is one jitted
-call: the cohort's clients side by side (``jax.vmap``), each a loop of
-plain steps; no kernels and no scanned rounds.  ``follow`` runs it in
+builds the initial weights itself from the seed).  A round runs the cohort
+in blocks of clients, one jitted call per block: the block's clients side
+by side (``jax.vmap``), each a loop of plain steps; no kernels and no
+scanned rounds.  The weighted-delta sum and the loss average's numerator
+and denominator are summed over the blocks.  The block is the largest
+divisor of the cohort whose client replicas (``block_size``) fit
+``BLOCK_SHARE`` of the device's memory, so a model at published widths
+holds one client at a time where the paper's models hold the whole cohort
+and do the arithmetic of a single call.  ``follow`` runs it in
 float32 at ``highest`` matmul precision.  With ``dtype=bfloat16`` it is the
 control: the configuration states float32, and bfloat16 is the step below.
 ``fault`` plants one of the faults the check must catch:
@@ -33,6 +39,12 @@ import numpy as np
 from chipbench import parts
 
 FAULTS = ("frozen", "half_batch", "no_exchange")
+# a block's client replicas may take this share of the device's memory; the
+# rest holds the server state, the running delta sum and the activations
+BLOCK_SHARE = 0.5
+# float32 words a client replica holds per parameter: its local weights,
+# their gradient and its weighted delta
+WORDS_PER_PARAM = 3
 # leaves whose first aggregated delta is under this share of the median
 # leaf's move by round-off alone and are left out of ``change_gap``
 STILL_LEAF = 1e-3
@@ -58,9 +70,11 @@ def _draws(key, t, ids, n_k, need):
     return jax.vmap(one)(ids, n_k)
 
 
-def _round(loss_fn, local, half, w, batches, weights, steps, lr, keep):
-    """One round's weighted delta sum and loss.  ``batches`` leaves are
-    [C, H, b, ...]; client k runs its first ``steps[k]`` local steps."""
+def _block(loss_fn, local, half, w, batches, weights, steps, lr, keep):
+    """One block of the cohort: the sum of its clients' weighted deltas,
+    and the numerator and denominator of the round's loss average.
+    ``batches`` leaves are [B, H, b, ...]; client k runs its first
+    ``steps[k]`` local steps; ``keep[k]`` = 0 leaves its delta out."""
     H = jax.tree.leaves(batches)[0].shape[1]
 
     def client(batch, weight, n_steps):
@@ -86,7 +100,33 @@ def _round(loss_fn, local, half, w, batches, weights, steps, lr, keep):
         lambda d: jnp.sum(d * keep.reshape((-1,) + (1,) * (d.ndim - 1)), 0),
         deltas)
     live = weights * (steps > 0)
-    return delta, jnp.sum(live * losses) / jnp.sum(live)
+    return delta, jnp.sum(live * losses), jnp.sum(live)
+
+
+def client_bytes(n_params: int, sample_bytes: int, local_steps: int,
+                 local_batch: int) -> int:
+    """What one client replica holds: ``WORDS_PER_PARAM`` float32 words
+    per parameter, and its H*b samples."""
+    return (WORDS_PER_PARAM * 4 * n_params
+            + local_steps * local_batch * sample_bytes)
+
+
+def block_size(cohort: int, per_client: int, memory_bytes) -> int:
+    """The largest divisor of ``cohort`` whose clients, ``per_client``
+    bytes each, fit ``BLOCK_SHARE`` of ``memory_bytes``; 1 where none
+    does, the whole cohort where the memory is unknown (None)."""
+    if memory_bytes is None:
+        return cohort
+    fits = max(1, int(BLOCK_SHARE * memory_bytes) // per_client)
+    return max(d for d in range(1, cohort + 1)
+               if cohort % d == 0 and d <= fits)
+
+
+def device_memory():
+    """The first device's ``bytes_limit``, or None where the backend does
+    not report it (the CPU)."""
+    stats = jax.devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
 
 
 def _host(tree):
@@ -99,10 +139,12 @@ def _leaf_norms(tree) -> np.ndarray:
 
 
 def follow(cell, clients, counts, seeds, rounds: int, dtype=jnp.float32,
-           fault=None, shards: int = 1) -> dict:
+           fault=None, shards: int = 1, memory_bytes=None) -> dict:
     """The first ``rounds`` rounds: ``loss`` and ``dnorm`` (the norm of
     the aggregated delta) per round, the per-leaf norms of round 0's
-    delta (``delta0``), and the initial and final weights (host)."""
+    delta (``delta0``), the initial and final weights (host), and the
+    ``block`` of clients run side by side.  ``memory_bytes`` (default:
+    the device's, ``device_memory``) sizes the block."""
     if fault is not None and fault not in FAULTS:
         raise ValueError(f"unknown fault {fault!r}; have {FAULTS}")
     model, config, shape = cell.model, cell.config, cell.traffic["cohort"]
@@ -124,10 +166,19 @@ def follow(cell, clients, counts, seeds, rounds: int, dtype=jnp.float32,
     with jax.default_matmul_precision(precision):
         w0 = cast(jax.jit(lambda k: model.init(k, config["model_config"]))(
             jax.random.PRNGKey(seeds.weights)))
-        step = jax.jit(partial(_round, model.loss, rules["local_opt"],
+        out["w0"] = _host(w0)
+        sample = sum(a[0].nbytes for a in clients[0].values())
+        B = block_size(C, client_bytes(
+            sum(x.size for x in jax.tree.leaves(w0)), sample, H, b),
+            device_memory() if memory_bytes is None else memory_bytes)
+        out["block"] = B
+        step = jax.jit(partial(_block, model.loss, rules["local_opt"],
                                fault == "half_batch"))
+        add = jax.jit(lambda x, y: jax.tree.map(jnp.add, x, y),
+                      donate_argnums=0)
         server_step = jax.jit(server.step)
         state = server.init(w0)
+        del w0
         for t in range(rounds):
             ids, weights, steps = cohort(t)
             rows = np.asarray(_draws(data_key, t, jnp.asarray(ids),
@@ -135,22 +186,28 @@ def follow(cell, clients, counts, seeds, rounds: int, dtype=jnp.float32,
             batches = {name: np.stack([clients[c][name][r] for c, r
                                        in zip(ids, rows)])
                        for name in clients[0]}
-            batches = {name: jnp.asarray(
-                a.reshape((C, H, b) + a.shape[2:])).astype(
-                    dtype if name in model.FLOAT_FIELDS else a.dtype)
-                for name, a in batches.items()}
-            delta, loss = step(server.params(state), batches,
-                               jnp.asarray(weights, dtype),
-                               jnp.asarray(steps), lr,
-                               jnp.asarray(keep, dtype))
-            out["loss"].append(float(loss))
+            batches = {name: a.reshape((C, H, b) + a.shape[2:])
+                       for name, a in batches.items()}
+            w, total = server.params(state), None
+            for lo in range(0, C, B):
+                part = slice(lo, lo + B)
+                got = step(w, {name: jnp.asarray(a[part]).astype(
+                                   dtype if name in model.FLOAT_FIELDS
+                                   else a.dtype)
+                               for name, a in batches.items()},
+                           jnp.asarray(weights[part], dtype),
+                           jnp.asarray(steps[part]), lr,
+                           jnp.asarray(keep[part], dtype))
+                total = got if total is None else add(total, got)
+            delta, num, den = total
+            out["loss"].append(float(num / den))
             out["dnorm"].append(float(np.linalg.norm(
                 _leaf_norms(_host(delta)))))
             if t == 0:
                 out["delta0"] = _leaf_norms(_host(delta))
             if fault != "frozen":
                 state = server_step(state, delta)
-        out["w0"], out["w"] = _host(w0), _host(server.params(state))
+        out["w"] = _host(server.params(state))
     return out
 
 

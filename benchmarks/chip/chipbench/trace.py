@@ -8,8 +8,10 @@ recorded trace:
 
 * ``busy_s``: per device, the union of its op intervals inside the window;
 * ``op_s``: self seconds per op (its short name, the HLO text before
-  `` = ``) inside the window -- an op's time less that of the ops nested
-  in it, as a ``while`` holds its body's -- mean over devices;
+  `` = ``) inside the window -- the time in which it is the innermost op
+  running, so an op's time less that of the ops nested in it, as a
+  ``while`` holds its body's -- mean over devices; they add up to
+  ``busy_s``;
 * ``gaps``: the longest idle stretches on the first device, each labelled
   by the benchmark span it fell in and the op that ran before it.
 """
@@ -17,6 +19,7 @@ from __future__ import annotations
 
 import bisect
 import glob
+import math
 import os
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
@@ -126,18 +129,34 @@ def short_name(name: str) -> str:
 
 
 def _self_ns(events) -> Dict[str, float]:
-    """Self time per short op name: each op's duration less the durations
-    of the ops that start and end inside it (one level down)."""
+    """Self time per short op name: the time in which the op is the latest
+    started of the ops running.  A nested op takes its interval out of the
+    op around it, as a ``while`` holds its body's; one that runs on past
+    the end of the op it started in (a loop's last body fusion can end
+    after its ``while`` in a TPU trace) takes the overlap too.  So the self
+    times add up to the union of the intervals."""
     out: Dict[str, float] = {}
-    stack: List[Tuple[float, str]] = []          # (end, short name)
+    running: List[Tuple[float, str]] = []        # (end, short name)
+    t = -math.inf
+
+    def advance(to):
+        nonlocal t
+        while running and t < to:
+            end, short = running[-1]
+            if end <= t:
+                running.pop()
+                continue
+            step = min(end, to)
+            out[short] += step - t
+            t = step
+        t = max(t, to)
+
     for name, s, d in sorted(events, key=lambda e: (e[1], -e[2])):
-        while stack and stack[-1][0] <= s:
-            stack.pop()
+        advance(s)
         short = short_name(name)
-        if stack and s + d <= stack[-1][0]:
-            out[stack[-1][1]] -= d
-        out[short] = out.get(short, 0.0) + d
-        stack.append((s + d, short))
+        out.setdefault(short, 0.0)
+        running.append((s + d, short))
+    advance(math.inf)
     return out
 
 

@@ -99,7 +99,7 @@ def main(argv=None) -> int:
     used = harness.check_devices(cell.chips)[:cell.chips]
     first = harness.checked_first_chunk(cell, args.seed, log)
     tr, plan, seeds = first.trainer, first.plan, first.seeds
-    packs = [r for r in tr.history if r.get("event") == "pack"]
+    packs = [r for r in first.events if r["event"] == "pack"]
     R = plan.chunk_rounds
     per_round = _timed(harness, tr, R, plan) / 1e3
     n = R * max(1, math.ceil(harness.TRACE_SECONDS / (per_round * R)))

@@ -1,0 +1,13 @@
+"""aggregate_ms: device ms per round under the ``fed.aggregate`` named scope
+(the weighted sum of the clients' deltas, any cross-chip sum, the delta's
+norm and the loss average): the self time of its ops in the traced
+stretch, mean over the cell's chips, over the stretch's rounds; 0.0 where
+no op ran under it.  Profiler trace (``chipbench.scopes``)."""
+
+SCOPE = "fed.aggregate"
+
+
+def read(ctx):
+    if ctx.layers is None:
+        return None
+    return ctx.layers.ms_per_round().get(SCOPE, 0.0)

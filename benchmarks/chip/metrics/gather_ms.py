@@ -1,0 +1,13 @@
+"""gather_ms: device ms per round under the ``fed.gather`` named scope (the
+cohort draw and the pick of each client's H*b rows): the self time of its
+ops in the traced stretch, mean over the cell's chips, over the stretch's
+rounds; 0.0 where no op ran under it.  Profiler trace
+(``chipbench.scopes``)."""
+
+SCOPE = "fed.gather"
+
+
+def read(ctx):
+    if ctx.layers is None:
+        return None
+    return ctx.layers.ms_per_round().get(SCOPE, 0.0)

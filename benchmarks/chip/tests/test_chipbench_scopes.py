@@ -7,6 +7,7 @@ import json
 import re
 from pathlib import Path
 
+import jax
 import pytest
 
 from chipbench import harness, registry, scopes, trace
@@ -166,30 +167,57 @@ def _committed_trace():
                  "spans": [tuple(s) for s in rec["spans"]]}
 
 
+def _context(cell, layers=None, reduction=None, events=()):
+    """A traced run's context as ``run_cell`` builds it, around the
+    committed trace's numbers."""
+    if layers is not None:
+        reduction = layers.base
+    return harness.Context(
+        cell=cell.name, config=cell.config, traffic=cell.traffic,
+        model=cell.model, chips=1, peaks=peaks("TPU v5 lite"),
+        setup_s=20.137, window_s=10.149, window_rounds=125,
+        window_history=[], events=list(events), trace=reduction,
+        layers=layers)
+
+
+def _through_traced(monkeypatch, data, window, rounds):
+    """``harness._traced`` on ``data`` in place of a profiler trace whose
+    ``bench.window`` span is ``window``."""
+    lo, hi = window
+    spans = [s for s in data["spans"] if s[0] != trace.WINDOW_SPAN]
+    spanned = dict(data, spans=spans + [(trace.WINDOW_SPAN, lo, hi - lo)])
+    monkeypatch.setattr(harness, "_run", lambda tr, n, plan: [])
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    monkeypatch.setattr(trace, "find_xplane", lambda d: "trace.xplane.pb")
+    monkeypatch.setattr(scopes, "load", lambda path: spanned)
+    return harness._traced(None, rounds, None, [0], lambda msg: None)
+
+
 @pytest.mark.parametrize("name,value", [
     ("round_ms", 81.192), ("setup_s", 20.137), ("mfu", 2.435347835024631),
     ("device_idle_frac", 0.9352106666666666)])
-def test_todays_metrics_read_the_same_on_the_committed_trace(name, value):
+def test_todays_metrics_read_the_same_on_the_committed_trace(
+        name, value, monkeypatch):
     """The metrics of BENCHMARK.json read from the scoped reduction's base
     what they read from ``trace.reduce``, and what they read before the
-    scopes existed."""
+    scopes existed; and so through ``harness._traced``, the traced run's
+    path."""
     rec, data = _committed_trace()
     window = tuple(rec["window"])
     scoped = {"devices": {d: [e + (scopes.UNSCOPED, 0) for e in evs]
                           for d, evs in data["devices"].items()},
               "spans": data["spans"]}
-    bench = registry.benchmark()
-    cell = harness.Cell.load(CELLS[1], bench)
-    readings = []
-    for red in (trace.reduce(data, window=window),
-                scopes.reduce(scoped, rounds=1, window=window).base):
-        ctx = harness.Context(
-            cell=cell.name, config=cell.config, traffic=cell.traffic,
-            model=cell.model, chips=1, peaks=peaks("TPU v5 lite"),
-            setup_s=20.137, window_s=10.149, window_rounds=125,
-            window_history=[], trace=red)
-        readings.append(registry.metric(name).read(ctx))
-    assert readings[0] == readings[1] == pytest.approx(value, rel=1e-12)
+    cell = harness.Cell.load(CELLS[1], registry.benchmark())
+    contexts = [
+        _context(cell, reduction=trace.reduce(data, window=window)),
+        _context(cell, reduction=scopes.reduce(scoped, rounds=1,
+                                               window=window).base),
+        _context(cell, layers=_through_traced(monkeypatch, scoped, window,
+                                              rounds=1))]
+    readings = [registry.metric(name).read(ctx) for ctx in contexts]
+    assert readings[0] == readings[1] == readings[2] == pytest.approx(
+        value, rel=1e-12)
 
 
 def _recorded(name):
@@ -213,3 +241,54 @@ def test_recorded_chip_trace_splits_into_layers(name):
     assert red.scope_s["fed.client"] > 0
     assert red.scope_s.get(scopes.UNSCOPED, 0.0) < 0.10 * busy
     assert sum(red.scope_s.values()) == pytest.approx(busy, rel=1e-6)
+
+
+SCOPE_METRICS = {"gather_ms": "fed.gather", "client_ms": "fed.client",
+                 "aggregate_ms": "fed.aggregate", "server_ms": "fed.server"}
+
+
+@pytest.mark.parametrize("name", ["lenet_scoped_trace_3ms.json",
+                                  "charlstm_scoped_trace_1ms.json"])
+def test_scope_metrics_add_up_to_the_busy_time(name, monkeypatch):
+    """The four scope metrics and ``unscoped`` add up to the busy ms per
+    round, read through ``harness._traced`` as a traced run reads them."""
+    rec, data = _recorded(name)
+    rounds = 2
+    layers = _through_traced(monkeypatch, data, tuple(rec["window"]),
+                             rounds)
+    ctx = _context(harness.Cell.load(CELLS[0], registry.benchmark()),
+                   layers=layers)
+    got = {m: registry.metric(m).read(ctx) for m in SCOPE_METRICS}
+    assert got["gather_ms"] > 0 and got["client_ms"] > 0
+    for metric, scope in SCOPE_METRICS.items():
+        assert got[metric] == layers.scope_s.get(scope, 0.0) / rounds * 1e3
+    unscoped = layers.ms_per_round().get(scopes.UNSCOPED, 0.0)
+    busy_ms = layers.base.busy_s[0] / rounds * 1e3
+    assert sum(got.values()) + unscoped == pytest.approx(busy_ms, rel=1e-5)
+
+
+@pytest.mark.parametrize("metric", sorted(SCOPE_METRICS))
+def test_scope_metric_reads_0_for_a_scope_with_no_ops(metric):
+    data = _synthetic()
+    data["devices"][0] = [e for e in data["devices"][0]
+                          if e[3] != SCOPE_METRICS[metric]]
+    layers = scopes.reduce(data, rounds=2)
+    ctx = _context(harness.Cell.load(CELLS[0], registry.benchmark()),
+                   layers=layers)
+    assert registry.metric(metric).read(ctx) == 0.0
+
+
+@pytest.mark.parametrize("metric", sorted(SCOPE_METRICS))
+def test_scope_metric_is_none_untraced(metric):
+    ctx = _context(harness.Cell.load(CELLS[0], registry.benchmark()))
+    assert registry.metric(metric).read(ctx) is None
+
+
+def test_pack_s_reads_the_first_chunks_pack_event():
+    cell, _ = tiny_cell(CELLS[0])
+    first = harness.checked_first_chunk(cell, 2**31 + 9, lambda msg: None)
+    (pack,) = [e for e in first.events if e["event"] == "pack"]
+    reader = registry.metric("pack_s")
+    assert reader.read(_context(cell, events=first.events)) \
+        == pack["seconds"] > 0
+    assert reader.read(_context(cell, events=[{"event": "plan"}])) is None

@@ -30,7 +30,9 @@ def test_busy_is_the_union_inside_the_window():
 
 def test_op_seconds_clipped_and_averaged_over_devices():
     red = trace.reduce(_data())
-    assert red.op_s["a"] == pytest.approx((200 + 500) / 2 * 1e-9)
+    # on device 0, b starts inside a and runs past it: the overlap is b's
+    assert red.op_s["a"] == pytest.approx((100 + 500) / 2 * 1e-9)
+    assert red.op_s["b"] == pytest.approx(200 / 2 * 1e-9)
     assert red.op_s["c"] == pytest.approx(400 / 2 * 1e-9)
     one = trace.reduce(_data(), devices=[1])
     assert one.op_s == pytest.approx({"a": 500e-9})
@@ -99,3 +101,40 @@ def test_recorded_chip_trace():
     # what its body ops leave uncovered
     assert 0 < red.op_s["while.215"] < (hi - lo) * 1e-9
     assert sum(red.op_s.values()) == pytest.approx(busy, rel=1e-6)
+
+
+def test_an_op_running_past_its_loop_counts_once():
+    """A loop body's last fusion that ends after its ``while`` (as the
+    char-LSTM's backward loop shows on the chip): the overlap is the
+    fusion's alone, and the self times add up to the busy time."""
+    data = {"devices": {0: [("%while.1 = (...) while(...)", 0, 1000),
+                            ("%fusion.2 = f32[] fusion(...)", 100, 300),
+                            ("%fusion.3 = f32[] fusion(...)", 900, 250),
+                            ("%copy.4 = f32[] copy(...)", 1100, 100)]},
+            "spans": [("bench.window", 0, 2000)]}
+    red = trace.reduce(data)
+    # copy.4 starts in fusion.3 and runs past it in turn
+    assert red.op_s == pytest.approx({"while.1": 600e-9, "fusion.2": 300e-9,
+                                      "fusion.3": 200e-9, "copy.4": 100e-9})
+    assert red.busy_s[0] == pytest.approx(1200e-9)
+    assert sum(red.op_s.values()) == pytest.approx(red.busy_s[0])
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_self_times_add_up_to_the_busy_time(seed):
+    """Random ops -- nested, overlapping, running past each other, cut by
+    the window: every nanosecond of busy time is one op's."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    starts = rng.integers(0, 10_000, 300).astype(float)
+    durations = rng.integers(0, 400, 300).astype(float)
+    data = {"devices": {0: [(f"%op.{i % 40} = f32[] fusion(...)", s, d)
+                            for i, (s, d) in enumerate(zip(starts,
+                                                           durations))]},
+            "spans": [("bench.window", 500, 9000)]}
+    red = trace.reduce(data)
+    busy, _ = _mask_busy_and_longest_gap(data["devices"][0], 500, 9500)
+    assert min(red.op_s.values()) >= 0
+    assert sum(red.op_s.values()) == pytest.approx(busy, rel=1e-12)
+    assert red.busy_s[0] == pytest.approx(busy, rel=1e-12)
