@@ -95,24 +95,90 @@ def lstm_init(key, vocab: int = SHAKESPEARE_VOCAB,
     }
 
 
+def _lstm_cell(wx, wh, b, h, c, xt):
+    """One time step: the activated gates (i, f, g, o), each [B,H], and the
+    new (h, c)."""
+    z = xt @ wx + h @ wh + b
+    i, f, g, o = jnp.split(z, 4, axis=-1)
+    i, f = jax.nn.sigmoid(i), jax.nn.sigmoid(f + 1.0)
+    g, o = jnp.tanh(g), jax.nn.sigmoid(o)
+    c = f * c + i * g
+    return (i, f, g, o), o * jnp.tanh(c), c
+
+
+def _lstm_scan(wx, wh, b, x):
+    """x [S,B,E] -> (hs, the four gates, c_prev), each [S,B,H]. h and c
+    are float32 whatever the weights' dtype."""
+    zeros = jnp.zeros((x.shape[1], wh.shape[0]))
+
+    def step(carry, xt):
+        h, c_prev = carry
+        gates, h, c = _lstm_cell(wx, wh, b, h, c_prev, xt)
+        return (h, c), (h, gates, c_prev)
+
+    _, out = jax.lax.scan(step, (zeros, zeros), x)
+    return out
+
+
+@jax.custom_vjp
+def lstm_recurrence(wx, wh, b, x):
+    """The LSTM over time: x [S,B,E] -> hs [S,B,H], from h = c = 0.
+
+    Its VJP runs the reverse-time scan with only (dh, dc) in the carry and
+    emits each step's gate gradient dz [B,4H]; the weight gradients are
+    then one contraction over all S*B rows, not an accumulator of wh's
+    shape read and written at every step."""
+    return _lstm_scan(wx, wh, b, x)[0]
+
+
+def _lstm_recurrence_fwd(wx, wh, b, x):
+    hs, gates, c_prev = _lstm_scan(wx, wh, b, x)
+    return hs, (wx, wh, b, x, hs, gates, c_prev)
+
+
+def _lstm_recurrence_bwd(res, dhs):
+    wx, wh, b, x, hs, gates, c_prev = res
+    S = x.shape[0]
+
+    def step(carry, r):
+        dh, dc = carry
+        t = S - 1 - r
+        at = lambda a: jax.lax.dynamic_index_in_dim(a, t, 0, keepdims=False)
+        i, f, g, o = (at(a) for a in gates)
+        c_prev_t = at(c_prev)
+        dh = dh + at(dhs)
+        tc = jnp.tanh(f * c_prev_t + i * g)
+        dc = dc + dh * o * (1.0 - tc * tc)
+        dz = jnp.concatenate([dc * g * i * (1.0 - i),
+                              dc * c_prev_t * f * (1.0 - f),
+                              dc * i * (1.0 - g * g),
+                              dh * tc * o * (1.0 - o)], -1)
+        return (dz @ wh.T, dc * f), dz
+
+    zeros = jnp.zeros_like(dhs[0])
+    # dz_rev[r] = dz_{S-1-r}: a scan that runs forward writes its stack in
+    # place, where a reverse scan would zero-fill it first
+    _, dz_rev = jax.lax.scan(step, (zeros, zeros), jnp.arange(S))
+    # one contraction over all S*B rows gives dwh, dwx and db: row t of the
+    # left side is [h_{t-1}, x_t, 1], in dz_rev's reversed time order
+    h_prev = jnp.concatenate([jnp.zeros_like(hs[:1]), hs[:-1]])
+    lhs = jnp.concatenate([h_prev, x, jnp.ones_like(x[..., :1])], -1)[::-1]
+    rows = lambda a: a.reshape(-1, a.shape[-1])
+    grads = rows(lhs).T @ rows(dz_rev)
+    H, E = wh.shape[0], wx.shape[0]
+    dx = (dz_rev @ wx.T)[::-1]
+    return (grads[H:H + E].astype(wx.dtype), grads[:H].astype(wh.dtype),
+            grads[H + E].astype(b.dtype), dx.astype(x.dtype))
+
+
+lstm_recurrence.defvjp(_lstm_recurrence_fwd, _lstm_recurrence_bwd)
+
+
 def lstm_apply(params, tokens):
     """tokens [B,S] -> logits [B,S,V]."""
-    B, S = tokens.shape
-    H = params["wh"].shape[0]
-    x = params["embed"][tokens]                    # [B,S,E]
-
-    def cell(carry, xt):
-        h, c = carry
-        z = xt @ params["wx"] + h @ params["wh"] + params["b"]
-        i, f, g, o = jnp.split(z, 4, axis=-1)
-        c = jax.nn.sigmoid(f + 1.0) * c + jax.nn.sigmoid(i) * jnp.tanh(g)
-        h = jax.nn.sigmoid(o) * jnp.tanh(c)
-        return (h, c), h
-
-    h0 = (jnp.zeros((B, H)), jnp.zeros((B, H)))
-    _, hs = jax.lax.scan(cell, h0, x.transpose(1, 0, 2))
-    hs = hs.transpose(1, 0, 2)                     # [B,S,H]
-    return hs @ params["head"] + params["head_b"]
+    x = params["embed"][tokens].transpose(1, 0, 2)  # [S,B,E]
+    hs = lstm_recurrence(params["wx"], params["wh"], params["b"], x)
+    return hs.transpose(1, 0, 2) @ params["head"] + params["head_b"]
 
 
 def lstm_loss(params, batch):

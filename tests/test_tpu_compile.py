@@ -5,20 +5,25 @@ misaligned block shapes, vector loads of scalars — so each main-path
 kernel, and the LeNet round program that calls the fused server update, is
 compiled here at real widths for one chip of a described ``v5e:2x2``
 topology.  Every compiled text must carry ``tpu_custom_call``: the kernel
-was compiled, not interpreted.  Nothing runs; a compile is not a chip run.
+was compiled, not interpreted.  The char-LSTM's local update is compiled
+the same way, to read which tensors its time-step loops carry.  Nothing
+runs; a compile is not a chip run.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
 file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import _lstm_reference
 from repro.core import RoundConfig, fedmom, round_step
+from repro.core.client import local_update
 from repro.kernels._device import kernel_platform
 from repro.kernels.client_step import kernel as cs_kernel
 from repro.kernels.fedmom_update import kernel as fm_kernel
@@ -107,3 +112,45 @@ def test_lenet_round_step_compiles(one_chip):
             lambda st, bt, w: round_step(small.lenet_loss, opt, st, bt, w,
                                          rcfg),
             state, batches, _sds((C,), one_chip))
+
+
+def _time_step_loop_carries(hlo_text):
+    """The carried shapes of each ``while`` whose body holds no other
+    ``while``: the time-step loops, nested in the local-step loop."""
+    bodies = {}
+    name = None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name is not None:
+            bodies[name].append(line)
+    loops = [re.search(r"= \((.*)\) while\(.*body=%([^,\s]+)", line)
+             for lines in bodies.values() for line in lines]
+    return [m.group(1) for m in loops if m and not any(
+        " while(" in line for line in bodies[m.group(2)])]
+
+
+@pytest.mark.parametrize("loss_name", ["lstm_loss", "reference"])
+def test_lstm_time_step_loops_carry_no_wh_gradient(one_chip, loss_name):
+    """The cohort's char-LSTM local update (C = 8 clients, H = 10 local
+    steps of b = 10 sequences of 80 tokens) compiled for the chip: neither
+    time-step loop of ``small.lstm_loss`` carries a per-client f32
+    [C, 128, 512] tensor (wh's gradient is formed after the reverse scan),
+    where autodiff of the plain scan cell accumulates it in its reverse
+    loop."""
+    C, H, b, S = 8, 10, 10, 80
+    loss = (small.lstm_loss if loss_name == "lstm_loss"
+            else _lstm_reference.lstm_loss)
+    params = jax.tree.map(
+        lambda s: _sds((C,) + s.shape, one_chip, s.dtype),
+        jax.eval_shape(small.lstm_init, jax.random.PRNGKey(0)))
+    tokens = _sds((C, H, b, S), one_chip, jnp.int32)
+    text = jax.jit(jax.vmap(
+        lambda p, bt: local_update(loss, p, bt, 0.05))).lower(
+            params, {"tokens": tokens, "labels": tokens}).compile().as_text()
+    carries = _time_step_loop_carries(text)
+    assert len(carries) == 2                 # the forward and reverse scans
+    wh_grad = [c for c in carries if "f32[8,128,512]" in c]
+    assert len(wh_grad) == (0 if loss_name == "lstm_loss" else 1)
